@@ -20,6 +20,7 @@ import argparse
 import json
 import sys
 import time
+import traceback
 
 # every BENCH_relay.json must report these serving modes
 RELAY_MODES = ("baseline", "relay", "relay_dram", "relay_batched",
@@ -56,12 +57,15 @@ def main(argv=None) -> None:
         micro_fns = [f for f in micro_fns if args.only in f.__name__]
 
     print("name,us_per_call,derived")
+    failed = []
     for fn in fig_fns + micro_fns:
         t0 = time.time()
         try:
             rows = fn()
-        except Exception as e:  # report, keep going
+        except Exception as e:  # report, run the rest, exit non-zero
+            traceback.print_exc()
             print(f"{fn.__name__},0,ERROR: {type(e).__name__}: {e}")
+            failed.append(fn.__name__)
             continue
         for name, us, derived in rows:
             print(f"{name},{us:.1f},{derived}")
@@ -82,16 +86,14 @@ def main(argv=None) -> None:
         print(f"# wrote {args.relay_json} in {time.time() - t0:.1f}s",
               file=sys.stderr)
 
-    # roofline summary (if the dry-run has produced artifacts)
-    try:
-        from benchmarks import roofline
-        rows = roofline.load()
-        for r in rows:
-            print(f"roofline/{r['arch']}/{r['shape']},"
-                  f"{r['roofline_bound_s'] * 1e6:.1f},"
-                  f"dominant={r['dominant']} useful={r['useful_ratio']}")
-    except Exception as e:
-        print(f"roofline,0,unavailable: {e}")
+    # roofline summary (empty unless the dry-run has produced artifacts)
+    from benchmarks import roofline
+    for r in roofline.load():
+        print(f"roofline/{r['arch']}/{r['shape']},"
+              f"{r['roofline_bound_s'] * 1e6:.1f},"
+              f"dominant={r['dominant']} useful={r['useful_ratio']}")
+    if failed:
+        raise SystemExit(f"benchmark phases raised: {failed}")
 
 
 if __name__ == "__main__":

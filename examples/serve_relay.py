@@ -9,7 +9,9 @@ tiers are enabled).
 
 Run:  PYTHONPATH=src python examples/serve_relay.py [--requests 100]
 
-The same launcher exposes every serving axis (see --help):
+With no arguments it serves the reduced --smoke model, sized for a
+CPU; pass arguments without --smoke to serve hstu_gr at its published
+widths.  The same launcher exposes every serving axis (see --help):
 
   --sim                         virtual-clock cluster sim at prod QPS
   --batched --max-batch 8       continuous micro-batching
@@ -24,4 +26,4 @@ import sys
 from repro.launch.serve import main
 
 if __name__ == "__main__":
-    main(sys.argv[1:] or ["--requests", "100", "--qps", "150"])
+    main(sys.argv[1:] or ["--smoke", "--requests", "100", "--qps", "150"])

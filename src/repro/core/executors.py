@@ -16,8 +16,8 @@ name and are selected per deployment via ``get_executor``:
     continuous micro-batching: compatible rank requests grouped by the
     per-instance ``BatchAggregator`` execute as ONE jitted call on
     bucketed shapes (``rank_group``), and per-request shapes snap to
-    the same bucket grid so batched and per-request scores agree
-    bit-for-bit (tests/test_batching.py).
+    the same bucket grid so batched and per-request scores agree to
+    fp32 rounding (tests/test_batching.py).
 
 An executor opts into runtime-driven batching by carrying a
 ``batching: BatchingConfig`` attribute and a ``rank_group(group)``
@@ -49,7 +49,8 @@ from repro.serving.batching import (BatchingConfig, PendingRank, bucket_of,
 
 from .cache import kv_nbytes
 from .costmodel import GRCostModel
-from .paging import DevicePagePool, PageLayout, PagedPsi, ceil_div
+from .paging import (DevicePagePool, PageLayout, PagedPsi, ceil_div,
+                     device_pages, device_zeros)
 from .types import UserMeta
 
 
@@ -92,7 +93,7 @@ def _pages_of(tokens: int, psi: PagedPsi) -> int:
     return page_bucket(tokens, psi.layout.page_tokens)
 
 
-def _page_launch_args(jnp, psis: Sequence[PagedPsi], np_bucket: int):
+def _page_launch_args(put, psis: Sequence[PagedPsi], np_bucket: int):
     """Stack per-member page tables — (slabs, n) int32 — into the
     (B, L, 2, np_bucket) launch table, padding with the pool's null
     (all-zero) page so padded tokens contribute silu(0) = 0 exactly,
@@ -104,7 +105,8 @@ def _page_launch_args(jnp, psis: Sequence[PagedPsi], np_bucket: int):
     pool's ``h2d`` ledger.  A member whose table exceeds ``np_bucket``
     is an error — truncating would silently drop cached pages from the
     gather (callers widen the launch bucket to the group's largest
-    member instead)."""
+    member instead).  ``put`` moves a host array to the launching
+    executor's device."""
     buf = psis[0].buffer
     null = buf.shape[0] - 1
     rows = []
@@ -122,22 +124,22 @@ def _page_launch_args(jnp, psis: Sequence[PagedPsi], np_bucket: int):
     if isinstance(pool, DevicePagePool):
         launch_buf = pool.device_view(buf)
     else:
-        launch_buf = jnp.asarray(buf)      # O(pool bytes) per launch
+        launch_buf = put(device_pages(buf))    # O(pool bytes) per launch
         if pool is not None:
             pool.h2d["launch_reships"] += 1
             pool.h2d["reshipped_bytes"] += int(buf.nbytes)
-    return launch_buf, jnp.asarray(np.stack(rows))
+    return launch_buf, put(np.stack(rows))
 
 
-def _gather_psi(jnp, buf, tables):
-    """Inside-jit gather: pool buffer (N + 1, pt, H, D) + launch tables
-    (B, L, 2, np) -> the (K, V) pytree of stacked (L, B, np * pt, H, D)
-    that ``rank_with_cache`` consumes.  On TPU the Pallas kernel
-    (``repro.kernels.paged_prefix_attn``) reads the pool through the
-    page-table BlockSpec index map instead."""
-    g = jnp.take(buf, tables, axis=0)      # (B, L, 2, np, pt, H, D)
-    B, L, _, npg, pt, H, D = g.shape
-    g = g.reshape(B, L, 2, npg * pt, H, D)
+def _gather_psi(jnp, buf, tables, heads: int):
+    """Inside-jit gather: pool buffer in its device layout (N + 1, pt,
+    H * D) + launch tables (B, L, 2, np) -> the (K, V) pytree of stacked
+    (L, B, np * pt, H, D) that ``rank_with_cache`` consumes.  The Pallas
+    kernel (``repro.kernels.paged_prefix_attn``) reads the pool through
+    the page-table BlockSpec index map instead."""
+    g = jnp.take(buf, tables, axis=0)      # (B, L, 2, np, pt, H * D)
+    B, L, _, npg, pt, hd = g.shape
+    g = g.reshape(B, L, 2, npg * pt, heads, hd // heads)
     k = jnp.transpose(g[:, :, 0], (1, 0, 2, 3, 4))
     v = jnp.transpose(g[:, :, 1], (1, 0, 2, 3, 4))
     return (k, v)
@@ -262,11 +264,18 @@ class LiveExecutor:
 
     def __init__(self, model, params, store,
                  cost: Optional[GRCostModel] = None, page_tokens: int = 0,
-                 segments: bool = False, device_pool: bool = False):
+                 segments: bool = False, device_pool: bool = False,
+                 device=None):
         import jax
         self._jax = jax
         self.model = model
-        self.params = params
+        # ``device`` binds the executor to one accelerator: params,
+        # launch inputs and the device page pool all live there, so the
+        # psi an instance produces is ranked where it rests.  None keeps
+        # JAX's default device.
+        self.device = device
+        self.params = (params if device is None
+                       else jax.device_put(params, device))
         self.store = store
         self.cost = cost or GRCostModel(model.cfg)
         self.page_tokens = int(page_tokens)
@@ -293,10 +302,15 @@ class LiveExecutor:
         # jitted launch (device-side gather; no host re-materialization)
         self._rank_pages = jax.jit(
             lambda p, buf, tables, incr, items: model.rank_with_cache(
-                p, _gather_psi(self._jax.numpy, buf, tables), incr, items))
+                p, _gather_psi(self._jax.numpy, buf, tables,
+                               model.cfg.n_heads), incr, items))
 
     def _round(self, n: int, m: int = 64) -> int:
         return max(m, (n + m - 1) // m * m)  # bucketed shapes: few recompiles
+
+    def _put(self, x):
+        """Host array -> this executor's device."""
+        return self._jax.device_put(x, self.device)
 
     def _pad_segments(self, kv, meta: UserMeta):
         """Append the segmented entry's span slots to live psi: one
@@ -321,9 +335,8 @@ class LiveExecutor:
         return tuple(pad(a) for a in kv)
 
     def pre_infer(self, meta: UserMeta) -> Tuple[Any, int, float]:
-        jnp = self._jax.numpy
         n = self._round(meta.prefix_len)
-        toks = jnp.asarray(
+        toks = self._put(
             np.resize(self.store.long_term(meta.user_id), n)[None, :])
         t0 = time.perf_counter()
         _, kv = self._prefill(self.params, toks)
@@ -333,12 +346,11 @@ class LiveExecutor:
         return kv, kv_nbytes(kv), ms
 
     def rank_cached(self, meta: UserMeta, psi) -> Tuple[Any, float]:
-        jnp = self._jax.numpy
-        incr = jnp.asarray(self.store.short_term(meta.user_id)[None, :])
-        items = jnp.asarray(self.store.candidates(meta.user_id)[None, :])
+        incr = self._put(self.store.short_term(meta.user_id)[None, :])
+        items = self._put(self.store.candidates(meta.user_id)[None, :])
         t0 = time.perf_counter()
         if isinstance(psi, PagedPsi):
-            buf, tables = _page_launch_args(jnp, [psi],
+            buf, tables = _page_launch_args(self._put, [psi],
                                             _pages_of(psi.n_tokens, psi))
             scores = self._rank_pages(self.params, buf, tables, incr, items)
         else:
@@ -347,12 +359,11 @@ class LiveExecutor:
         return scores, (time.perf_counter() - t0) * 1e3
 
     def rank_full(self, meta: UserMeta) -> Tuple[Any, float]:
-        jnp = self._jax.numpy
         n = self._full_pad(meta.prefix_len)
-        pref = jnp.asarray(
+        pref = self._put(
             np.resize(self.store.long_term(meta.user_id), n)[None, :])
-        incr = jnp.asarray(self.store.short_term(meta.user_id)[None, :])
-        items = jnp.asarray(self.store.candidates(meta.user_id)[None, :])
+        incr = self._put(self.store.short_term(meta.user_id)[None, :])
+        items = self._put(self.store.candidates(meta.user_id)[None, :])
         t0 = time.perf_counter()
         scores = self._rank_full(self.params, pref, incr, items)
         scores.block_until_ready()
@@ -382,7 +393,7 @@ class LiveExecutor:
         """Scatter freshly written ``pages`` (already staged in the
         host buffer) into the device-resident pool.  Returns the bytes
         moved over the H2D link (== len(pages) * page_bytes)."""
-        return pool.scatter(pages, host_buffer)
+        return pool.scatter(pages, host_buffer, device=self.device)
 
     def free_pages(self, pool, pages: Sequence[int]) -> None:
         """Return pages to the pool's free list (pin/zombie protection
@@ -402,7 +413,8 @@ class BatchedLiveExecutor(LiveExecutor):
         axis to the shared ``BUCKETS`` grid (psi zero-padded, which is
         exact for HSTU's silu attention; full-rank prefix tokens tiled,
         matching what the per-request call does after bucketing), so
-        batched scores equal per-request scores bit-for-bit;
+        batched scores equal per-request scores up to the reduction
+        order of the differently batched program;
       * the batch axis snaps to a power-of-two grid by repeating the
         first member (row-independent compute, sliced off afterwards),
         bounding the jit cache to #buckets x log2(max_batch) entries —
@@ -419,10 +431,10 @@ class BatchedLiveExecutor(LiveExecutor):
                  cost: Optional[GRCostModel] = None,
                  batching: Optional[BatchingConfig] = None,
                  page_tokens: int = 0, segments: bool = False,
-                 device_pool: bool = False):
+                 device_pool: bool = False, device=None):
         super().__init__(model, params, store, cost,
                          page_tokens=page_tokens, segments=segments,
-                         device_pool=device_pool)
+                         device_pool=device_pool, device=device)
         self.batching = batching or BatchingConfig()
         self._warmed: set = set()
 
@@ -464,7 +476,7 @@ class BatchedLiveExecutor(LiveExecutor):
                           else self.store.candidates(w.user_id)
                           for w in rows])
         t0 = time.perf_counter()
-        incr, items = jnp.asarray(incr), jnp.asarray(items)
+        incr, items = self._put(incr), self._put(items)
         if isinstance(group[0].psi, PagedPsi):
             # rank_with_pages: ONE launch keyed (page-count bucket,
             # batch grid); K/V stay in the page pool and are gathered
@@ -477,13 +489,14 @@ class BatchedLiveExecutor(LiveExecutor):
             pt = group[0].psi.layout.page_tokens
             npb = max([page_bucket(bucket, pt)]
                       + [_pages_of(w.psi.n_tokens, w.psi) for w in rows])
-            buf, tables = _page_launch_args(jnp, [w.psi for w in rows], npb)
+            buf, tables = _page_launch_args(self._put,
+                                            [w.psi for w in rows], npb)
             scores = self._rank_pages(self.params, buf, tables, incr, items)
         elif group[0].psi is not None:        # homogeneous by aggregator key
             kv = stack_psi(jnp, [w.psi for w in rows], bucket)
             scores = self._rank(self.params, kv, incr, items)
         else:
-            pref = jnp.asarray(np.stack([
+            pref = self._put(np.stack([
                 np.resize(self.store.long_term(w.user_id), bucket)
                 for w in rows]))
             scores = self._rank_full(self.params, pref, incr, items)
@@ -500,14 +513,13 @@ class BatchedLiveExecutor(LiveExecutor):
         first member, and each member's psi slice — rows are
         independent under batched compute — is bit-identical to the psi
         its own per-request ``pre_infer`` call would have produced."""
-        jnp = self._jax.numpy
         n = self._round(max(m.prefix_len for m in metas))
         rows = list(metas)
         rows += [metas[0]] * (self._batch_grid(len(metas)) - len(metas))
         toks = np.stack([np.resize(self.store.long_term(m.user_id), n)
                          for m in rows])
         t0 = time.perf_counter()
-        _, kv = self._prefill(self.params, jnp.asarray(toks))
+        _, kv = self._prefill(self.params, self._put(toks))
         kv = self._jax.block_until_ready(kv)
         ms = (time.perf_counter() - t0) * 1e3
         outs = []
@@ -539,32 +551,35 @@ class BatchedLiveExecutor(LiveExecutor):
         from collections import Counter
         jax, jnp = self._jax, self._jax.numpy
         cfg = self.model.cfg
+        dt = jnp.dtype(cfg.dtype)
+        zeros = lambda shape, dtype=jnp.int32: device_zeros(
+            shape, dtype, self.device)
         freq = Counter(bucket_of(int(n)) for n in prefix_lens)
         buckets = sorted(b for b, _ in
                          freq.most_common(self.batching.max_buckets_live))
         sizes = sorted({self._batch_grid(int(b)) for b in batch_sizes})
+        buf = None
+        if self.page_tokens and pool_pages:
+            buf = zeros((pool_pages + 1, self.page_tokens,
+                         cfg.n_heads * cfg.head_dim), dt)
         done = []
         for bucket in buckets:
             for nb in sizes:
                 key = (bucket, nb, incr_len, n_items)
                 if key in self._warmed:
                     continue
-                z = jnp.zeros(
-                    (cfg.n_layers, nb, bucket, cfg.n_heads, cfg.head_dim),
-                    jnp.dtype(cfg.dtype))
-                incr = jnp.zeros((nb, incr_len), jnp.int32)
-                items = jnp.zeros((nb, n_items), jnp.int32)
+                z = zeros((cfg.n_layers, nb, bucket, cfg.n_heads,
+                           cfg.head_dim), dt)
+                incr = zeros((nb, incr_len))
+                items = zeros((nb, n_items))
                 jax.block_until_ready(
                     self._rank(self.params, (z, z), incr, items))
-                pref = jnp.zeros((nb, bucket), jnp.int32)
+                pref = zeros((nb, bucket))
                 jax.block_until_ready(
                     self._rank_full(self.params, pref, incr, items))
-                if self.page_tokens and pool_pages:
+                if buf is not None:
                     npb = page_bucket(bucket, self.page_tokens)
-                    buf = jnp.zeros(
-                        (pool_pages + 1, self.page_tokens,
-                         cfg.n_heads, cfg.head_dim), jnp.dtype(cfg.dtype))
-                    tables = jnp.zeros((nb, cfg.n_layers, 2, npb), jnp.int32)
+                    tables = zeros((nb, cfg.n_layers, 2, npb))
                     jax.block_until_ready(self._rank_pages(
                         self.params, buf, tables, incr, items))
                 self._warmed.add(key)

@@ -163,6 +163,25 @@ class PagePool:
                 self._pins[p] = n
 
 
+def device_pages(pages: np.ndarray) -> np.ndarray:
+    """A page buffer ``(n, page_tokens, H, D)`` as the device stores
+    it, ``(n, page_tokens, H * D)`` — a view, no copy."""
+    return pages.reshape(pages.shape[0], pages.shape[1], -1)
+
+
+def device_zeros(shape, dtype, device=None):
+    """Zeros filled on ``device`` itself and committed there (None:
+    JAX's default device).  ``jnp.zeros(..., device=d)`` fills on the
+    default device and then copies to ``d``, which for a pool-sized
+    buffer costs the pool's bytes a second time on the default device."""
+    import jax
+    import jax.numpy as jnp
+    if device is None:
+        return jnp.zeros(shape, dtype)
+    with jax.default_device(device):
+        return jax.device_put(jnp.zeros(shape, dtype), device)
+
+
 _SCATTER_JIT = None
 
 
@@ -196,27 +215,36 @@ class DevicePagePool(PagePool):
     (``PagedPsi.materialize`` on evict-spill / handoff-extract); the
     device buffer mirrors it incrementally, starting from device-side
     zeros so ``h2d["bytes_scattered"]`` counts exactly the inserted
-    page bytes."""
+    page bytes.
+
+    On the device a page is stored as ``(page_tokens, H * D)`` rows
+    (``device_pages``): the same bytes as the host's ``(page_tokens, H,
+    D)`` page.  With (H, D) = (4, 64) as the two minor axes a page does
+    not fill the TPU's (8, 128) tile, and the compiler then lays the
+    pool out page-minor and relays ALL of it on every scatter and
+    gather (2x and 1x the pool in temporaries for the v5e compiler);
+    flat rows tile exactly, so the donated scatter is truly in place."""
 
     def __init__(self, n_pages: int, page_bytes: int):
         super().__init__(n_pages, page_bytes)
         self.device_buffer = None           # lazily shaped, jax array
 
-    def ensure_device(self, host_buffer: np.ndarray):
+    def ensure_device(self, host_buffer: np.ndarray, device=None):
         """Create the resident buffer on first use — device-side zeros
         (matching the zero-filled host pool), so creation itself moves
-        no bytes over the link."""
+        no bytes over the link.  ``device`` is the owning executor's
+        (None: JAX's default device); the buffer stays there."""
         if self.device_buffer is None:
-            import jax.numpy as jnp
-            self.device_buffer = jnp.zeros(host_buffer.shape,
-                                           host_buffer.dtype)
+            self.device_buffer = device_zeros(
+                device_pages(host_buffer).shape, host_buffer.dtype, device)
         return self.device_buffer
 
     def device_view(self, host_buffer: np.ndarray):
         """The resident pool buffer a launch passes by reference."""
         return self.ensure_device(host_buffer)
 
-    def scatter(self, pages: Sequence[int], host_buffer: np.ndarray) -> int:
+    def scatter(self, pages: Sequence[int], host_buffer: np.ndarray,
+                device=None) -> int:
         """Land freshly written ``pages`` (already sliced into
         ``host_buffer``) in the device-resident pool.  The page-id axis
         pads to a power-of-two grid by repeating the first page (same
@@ -227,15 +255,17 @@ class DevicePagePool(PagePool):
         pages = [int(p) for p in pages]
         if not pages:
             return 0
-        import jax.numpy as jnp
-        self.ensure_device(host_buffer)
+        import jax
+        buf = self.ensure_device(host_buffer, device)
         grid = 1
         while grid < len(pages):
             grid *= 2
         idx = np.asarray(pages + [pages[0]] * (grid - len(pages)), np.int32)
+        # donation invalidates ``buf`` on an accelerator: only the
+        # returned array may be read after this call
         self.device_buffer = _scatter_jit()(
-            self.device_buffer, jnp.asarray(idx),
-            jnp.asarray(host_buffer[idx]))
+            buf, jax.device_put(idx, device),
+            jax.device_put(device_pages(host_buffer[idx]), device))
         nbytes = len(pages) * self.page_bytes
         self.h2d["bytes_scattered"] += nbytes
         self.h2d["pages_scattered"] += len(pages)

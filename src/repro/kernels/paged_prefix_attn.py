@@ -16,8 +16,9 @@ softmax running max/denominator), the aggregation splits exactly into
 a prefix part and a new-token part.  The kernel runs two phases that
 share one f32 accumulation chain:
 
-  phase 1  grid (B, H, nq, n_pages): K/V blocks fetched via
-           ``table[b, ip]`` from the page pool; every query sees the
+  phase 1  grid (B, nq, n_pages): K/V pages — all heads, in the device
+           pool's ``(page_tokens, H * D)`` layout — fetched via
+           ``table[b, ip]``, heads looped in-kernel; every query sees the
            whole prefix, so the only mask is per-row residency
            (``ip * page_tokens + j < prefix_len[b]``).  Emits the f32
            partial sums.
@@ -48,34 +49,46 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def _page_heads(q_ref, k_ref, v_ref, acc_ref, keep, scale, inv_n):
+    """Accumulate one page into every head's partial sum.  The page
+    block is ``(1, page_tokens, H * D)``: the device pool's layout
+    (``repro.core.paging.DevicePagePool``), which meets the TPU's
+    (8, 128) tiling where a one-head ``(page_tokens, 1, D)`` block does
+    not.  Each page is fetched once per (row, query block) and the head
+    loop runs inside the kernel."""
+    for h in range(q_ref.shape[1]):
+        q = q_ref[0, h].astype(jnp.float32)        # (bq, D)
+        hd = pl.ds(h * q.shape[1], q.shape[1])
+        k = k_ref[0, :, hd].astype(jnp.float32)    # (page_tokens, D)
+        v = v_ref[0, :, hd].astype(jnp.float32)
+        logits = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        a = jnp.where(keep, jax.nn.silu(logits) * inv_n, 0.0)
+        acc_ref[h] += jax.lax.dot_general(
+            a, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+
 def _prefix_pages_kernel(table_ref, plen_ref, q_ref, k_ref, v_ref, o_ref,
                          acc_ref, *, scale, inv_n, page_tokens, n_pages):
     """Phase 1: accumulate the prefix contribution, one page per step."""
-    ip = pl.program_id(3)
+    ip = pl.program_id(2)
 
     @pl.when(ip == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     b = pl.program_id(0)
-    q = q_ref[0, 0].astype(jnp.float32)
-    k = k_ref[0, :, 0].astype(jnp.float32)     # (page_tokens, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    logits = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
-    a = jax.nn.silu(logits) * inv_n
-    bq = q.shape[0]
+    bq = q_ref.shape[2]
     ki = ip * page_tokens + jax.lax.broadcasted_iota(
         jnp.int32, (bq, page_tokens), 1)
-    a = jnp.where(ki < plen_ref[b], a, 0.0)   # residency / padding mask
-    acc_ref[...] += jax.lax.dot_general(
-        a, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    keep = ki < plen_ref[b]                    # residency / padding mask
+    _page_heads(q_ref, k_ref, v_ref, acc_ref, keep, scale, inv_n)
 
     @pl.when(ip == n_pages - 1)
     def _done():
-        o_ref[0, 0] = acc_ref[...]
+        o_ref[0] = acc_ref[...]
 
 
 def _new_tokens_kernel(q_ref, k_ref, v_ref, part_ref, o_ref, acc_ref, *,
@@ -105,9 +118,10 @@ def _new_tokens_kernel(q_ref, k_ref, v_ref, part_ref, o_ref, acc_ref, *,
         is_item_q = qi >= n_incr
         is_item_k = ki >= n_incr
         self_key = ki == qi
-        items_ok = jnp.where(is_item_q,
-                             jnp.logical_or(~is_item_k, self_key), True)
-        a = jnp.where(jnp.logical_and(causal, items_ok), a, 0.0)
+        # pure logical ops (see prefix_rank_attn: a boolean select lowers
+        # to a truncation the TPU compiler refuses)
+        items_ok = ~is_item_q | ~is_item_k | self_key
+        a = jnp.where(causal & items_ok, a, 0.0)
         acc_ref[...] += jax.lax.dot_general(
             a, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -126,8 +140,9 @@ def paged_prefix_rank_attn(q, k_pages, v_pages, page_table, prefix_lens,
     """Rank with psi gathered from the page pool.
 
     q:                (B, H, Sq, D)   incr + item queries
-    k_pages, v_pages: (N + 1, page_tokens, H, D) pool buffers — row N is
-                      the all-zero null page used to pad tables
+    k_pages, v_pages: (N + 1, page_tokens, H * D) pool buffers in the
+                      device layout — row N is the all-zero null page
+                      used to pad tables
     page_table:       (B, n_pages) int32 page ids for each row's prefix
                       (pad with the null page up to the bucket)
     prefix_lens:      (B,) int32 true prefix tokens per row
@@ -152,18 +167,18 @@ def paged_prefix_rank_attn(q, k_pages, v_pages, page_table, prefix_lens,
     # --- phase 1: prefix pages via the page-table index map ---------------
     grid1 = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                 # page_table, prefix_lens
-        grid=(B, H, nq, n_pages),
+        grid=(B, nq, n_pages),
         in_specs=[
-            pl.BlockSpec((1, 1, bq, D),
-                         lambda b, h, iq, ip, tr, lr: (b, h, iq, 0)),
-            pl.BlockSpec((1, page_tokens, 1, D),
-                         lambda b, h, iq, ip, tr, lr: (tr[b, ip], 0, h, 0)),
-            pl.BlockSpec((1, page_tokens, 1, D),
-                         lambda b, h, iq, ip, tr, lr: (tr[b, ip], 0, h, 0)),
+            pl.BlockSpec((1, H, bq, D),
+                         lambda b, iq, ip, tr, lr: (b, 0, iq, 0)),
+            pl.BlockSpec((1, page_tokens, H * D),
+                         lambda b, iq, ip, tr, lr: (tr[b, ip], 0, 0)),
+            pl.BlockSpec((1, page_tokens, H * D),
+                         lambda b, iq, ip, tr, lr: (tr[b, ip], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq, D),
-                               lambda b, h, iq, ip, tr, lr: (b, h, iq, 0)),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        out_specs=pl.BlockSpec((1, H, bq, D),
+                               lambda b, iq, ip, tr, lr: (b, 0, iq, 0)),
+        scratch_shapes=[pltpu.VMEM((H, bq, D), jnp.float32)],
     )
     kernel1 = functools.partial(
         _prefix_pages_kernel, scale=scale, inv_n=inv_n,
@@ -206,33 +221,24 @@ def _segment_pages_kernel(table_ref, pos_ref, valid_ref, q_ref, qpos_ref,
     fresh token between two cached segments must not see the later
     segment; items' positions exceed every cached position, so the
     same causal test covers them)."""
-    ip = pl.program_id(3)
+    ip = pl.program_id(2)
 
     @pl.when(ip == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     b = pl.program_id(0)
-    q = q_ref[0, 0].astype(jnp.float32)
-    k = k_ref[0, :, 0].astype(jnp.float32)     # (page_tokens, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    logits = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
-    a = jax.nn.silu(logits) * inv_n
-    bq = q.shape[0]
+    bq = q_ref.shape[2]
     j = jax.lax.broadcasted_iota(jnp.int32, (bq, page_tokens), 1)
-    qp = qpos_ref[0].reshape(bq, 1)            # global query positions
+    qp = qpos_ref[0]                           # (bq, 1) global positions
     resident = j < valid_ref[b, ip]
     causal = pos_ref[b, ip] + j <= qp
-    a = jnp.where(jnp.logical_and(resident, causal), a, 0.0)
-    acc_ref[...] += jax.lax.dot_general(
-        a, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    _page_heads(q_ref, k_ref, v_ref, acc_ref, resident & causal, scale,
+                inv_n)
 
     @pl.when(ip == n_pages - 1)
     def _done():
-        o_ref[0, 0] = acc_ref[...]
+        o_ref[0] = acc_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -247,8 +253,9 @@ def segment_rank_attn(q, k_pages, v_pages, page_table, page_pos,
     fresh tokens interleave between them at their global positions.
 
     q:                (B, H, Sq, D) FRESH tokens (fresh incr + items)
-    k_pages, v_pages: (N + 1, page_tokens, H, D) pool buffers — row N
-                      is the all-zero null page used to pad tables
+    k_pages, v_pages: (N + 1, page_tokens, H * D) pool buffers in the
+                      device layout — row N is the all-zero null page
+                      used to pad tables
     page_table:       (B, n_pages) int32 page ids over the row's cached
                       spans, in span order (pad with the null page)
     page_pos:         (B, n_pages) int32 global position of each page's
@@ -280,25 +287,26 @@ def segment_rank_attn(q, k_pages, v_pages, page_table, page_pos,
     inv_n = 1.0 / (n_total or (n_pages * page_tokens + Sq))
 
     # --- phase 1: cached spans via the segment-table index map ------------
+    # q_pos is handed over as a (B, Sq, 1) column view: a (1, bq) block
+    # over (B, Sq) has a second-minor block dim of 1, which the TPU
+    # tiling refuses for B > 1, and the column arrives in the (query,
+    # key) mask orientation without an in-kernel relayout
     grid1 = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,           # page_table, page_pos, page_valid
-        grid=(B, H, nq, n_pages),
+        grid=(B, nq, n_pages),
         in_specs=[
-            pl.BlockSpec((1, 1, bq, D),
-                         lambda b, h, iq, ip, tr, pr, vr: (b, h, iq, 0)),
-            pl.BlockSpec((1, bq),
-                         lambda b, h, iq, ip, tr, pr, vr: (b, iq)),
-            pl.BlockSpec((1, page_tokens, 1, D),
-                         lambda b, h, iq, ip, tr, pr, vr:
-                         (tr[b, ip], 0, h, 0)),
-            pl.BlockSpec((1, page_tokens, 1, D),
-                         lambda b, h, iq, ip, tr, pr, vr:
-                         (tr[b, ip], 0, h, 0)),
+            pl.BlockSpec((1, H, bq, D),
+                         lambda b, iq, ip, tr, pr, vr: (b, 0, iq, 0)),
+            pl.BlockSpec((1, bq, 1),
+                         lambda b, iq, ip, tr, pr, vr: (b, iq, 0)),
+            pl.BlockSpec((1, page_tokens, H * D),
+                         lambda b, iq, ip, tr, pr, vr: (tr[b, ip], 0, 0)),
+            pl.BlockSpec((1, page_tokens, H * D),
+                         lambda b, iq, ip, tr, pr, vr: (tr[b, ip], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq, D),
-                               lambda b, h, iq, ip, tr, pr, vr:
-                               (b, h, iq, 0)),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        out_specs=pl.BlockSpec((1, H, bq, D),
+                               lambda b, iq, ip, tr, pr, vr: (b, 0, iq, 0)),
+        scratch_shapes=[pltpu.VMEM((H, bq, D), jnp.float32)],
     )
     kernel1 = functools.partial(
         _segment_pages_kernel, scale=scale, inv_n=inv_n,
@@ -307,7 +315,8 @@ def segment_rank_attn(q, k_pages, v_pages, page_table, page_pos,
         kernel1, grid_spec=grid1,
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, D), jnp.float32),
         interpret=interpret,
-    )(page_table, page_pos, page_valid, q, q_pos, k_pages, v_pages)
+    )(page_table, page_pos, page_valid, q, q_pos[:, :, None], k_pages,
+      v_pages)
 
     # --- phase 2: dense fresh tokens, identical to the prefix path --------
     kernel2 = functools.partial(
@@ -339,7 +348,8 @@ def pack_segments(k_cached, v_cached, spans, page_tokens: int,
     ``spans[b]`` is an ordered list of (global_start, length) pairs.
     Every span pads to whole pages (the store's residency unit).
     Returns (k_pages, v_pages, table, page_pos, page_valid) with the
-    all-zero null page as the last pool row."""
+    pool in the device layout (N + 1, page_tokens, H * D) and the
+    all-zero null page as its last row."""
     k_cached, v_cached = np.asarray(k_cached), np.asarray(v_cached)
     B, H, C, D = k_cached.shape
     per_row = [sum(-(-int(ln) // page_tokens) for _, ln in row)
@@ -369,7 +379,9 @@ def pack_segments(k_cached, v_cached, spans, page_tokens: int,
                 pid += 1
                 slot += 1
             off += int(ln)
-    return kp, vp, table, page_pos, page_valid
+    return (kp.reshape(total + 1, page_tokens, H * D),
+            vp.reshape(total + 1, page_tokens, H * D), table, page_pos,
+            page_valid)
 
 
 def pack_pages(k_dense, v_dense, prefix_lens, page_tokens: int,
@@ -377,7 +389,8 @@ def pack_pages(k_dense, v_dense, prefix_lens, page_tokens: int,
     """Test/reference helper: slice dense per-row prefixes — (B, H, P,
     D) — into pool buffers + page tables, mimicking what the paged HBM
     store does at insert.  Returns (k_pages, v_pages, table (B, np),
-    prefix_lens i32); the last pool row is the all-zero null page."""
+    prefix_lens i32) with the pool in the device layout (N + 1,
+    page_tokens, H * D); its last row is the all-zero null page."""
     k_dense, v_dense = np.asarray(k_dense), np.asarray(v_dense)
     B, H, P, D = k_dense.shape
     plens = np.asarray(prefix_lens, np.int32)
@@ -395,4 +408,5 @@ def pack_pages(k_dense, v_dense, prefix_lens, page_tokens: int,
             vp[pid, :hi - lo] = np.moveaxis(v_dense[b, :, lo:hi], 0, 1)
             table[b, j] = pid
             pid += 1
-    return kp, vp, table, plens
+    return (kp.reshape(total + 1, page_tokens, H * D),
+            vp.reshape(total + 1, page_tokens, H * D), table, plens)

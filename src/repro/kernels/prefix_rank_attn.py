@@ -50,9 +50,10 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, *, scale, inv_n, bq, bk,
         is_item_q = qi >= n_incr
         is_item_k = ki >= n_prefix + n_incr
         self_key = ki == qi + n_prefix
-        items_ok = jnp.where(is_item_q,
-                             jnp.logical_or(~is_item_k, self_key), True)
-        a = jnp.where(jnp.logical_and(causal, items_ok), a, 0.0)
+        # pure logical ops: a boolean select here lowers to an i8 -> i1
+        # truncation that the TPU compiler refuses
+        items_ok = ~is_item_q | ~is_item_k | self_key
+        a = jnp.where(causal & items_ok, a, 0.0)
         acc_ref[...] += jax.lax.dot_general(
             a, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)

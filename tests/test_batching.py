@@ -1,6 +1,6 @@
 """Correctness contract for continuous micro-batching (the contract
 promised by ``repro/serving/batching.py``): batched scores equal
-per-request scores — across every ``BUCKETS`` boundary (n, n+1, exact
+per-request scores, to fp32 rounding — across every ``BUCKETS`` boundary (n, n+1, exact
 bucket), with mixed prefix lengths inside one group (padded-key
 masking), and through the registered ``batched`` executor end-to-end
 under ``RelayRuntime``, not just the raw ``BatchedRankExecutor``.
@@ -31,6 +31,13 @@ CFG = get_config("hstu_gr", smoke=True)
 COST = GRCostModel(CFG)
 COST_FULL = GRCostModel(get_config("hstu_gr"))
 N_ITEMS, INCR = 16, 8
+# A batched launch and a per-request launch are different XLA programs
+# (the batch extent differs), and XLA may order their reductions
+# differently, so their fp32 scores agree to rounding, not bit for bit:
+# at most 5.6e-6 apart on scores of magnitude 1-10 (XLA CPU, JAX 0.9.0).
+# The bound below leaves about 4x margin over that.  Where both sides
+# run the same program, the tests keep exact equality.
+REORDER_TOL = dict(rtol=2e-5, atol=2e-5)
 
 
 @pytest.fixture(scope="module")
@@ -78,7 +85,9 @@ def test_batched_executor_registered(live):
 @pytest.mark.parametrize("boundary", [64, 128])
 def test_batched_matches_per_request_at_bucket_boundaries(live, boundary):
     """n just-below, exactly-at, and just-above a BUCKETS edge: batched
-    group scores bit-match the per-request rank_cached scores."""
+    group scores match the per-request rank_cached scores — bit for bit
+    for a one-member group (the same program), to fp32 rounding for a
+    two-member group (a different program)."""
     _, _, _, ex = live
     for base_uid, plens in ((10, (boundary - 1, boundary)),
                             (20, (boundary + 1,))):
@@ -92,7 +101,11 @@ def test_batched_matches_per_request_at_bucket_boundaries(live, boundary):
         scores, ms = ex.rank_group(group)
         assert ms > 0
         for got, want in zip(scores, singles):
-            np.testing.assert_array_equal(np.asarray(got), want)
+            if len(group) == 1:
+                np.testing.assert_array_equal(np.asarray(got), want)
+            else:
+                np.testing.assert_allclose(np.asarray(got), want,
+                                           **REORDER_TOL)
 
 
 def test_mixed_prefix_lengths_one_group_padded_keys_exact(live):
@@ -110,9 +123,9 @@ def test_mixed_prefix_lengths_one_group_padded_keys_exact(live):
     assert lens == {192, 256}, "group must mix psi lengths to pad"
     scores, _ = ex.rank_group(group)
     for got, want in zip(scores, singles):
-        np.testing.assert_array_equal(np.asarray(got), want)
+        np.testing.assert_allclose(np.asarray(got), want, **REORDER_TOL)
     # padding is explicit and exact: manually padded psi reproduces the
-    # batched member bit-for-bit through the unjitted model call
+    # batched member through the unjitted model call
     w = group[0]
     kp, vp = pad_psi(jax.numpy, w.psi, 256)
     want = model.rank_with_cache(
@@ -125,7 +138,7 @@ def test_mixed_prefix_lengths_one_group_padded_keys_exact(live):
 
 def test_batched_full_rank_matches_per_request(live):
     """Miss-fallback members (psi=None) batch through full_rank and
-    bit-match the per-request rank_full path."""
+    match the per-request rank_full path to fp32 rounding."""
     _, _, _, ex = live
     group, singles = [], []
     for uid, plen in ((40, 100), (41, 127), (42, 65)):
@@ -135,22 +148,26 @@ def test_batched_full_rank_matches_per_request(live):
         group.append(_work(meta, None))
     scores, _ = ex.rank_group(group)
     for got, want in zip(scores, singles):
-        np.testing.assert_array_equal(np.asarray(got), want)
+        np.testing.assert_allclose(np.asarray(got), want, **REORDER_TOL)
 
 
 def test_batch_axis_padding_is_row_independent(live):
     """A 3-deep group snaps to the 4-row grid by repeating row 0; the
-    real members' scores must be unaffected — compare against the same
-    group run as singletons."""
+    real members' scores must be unaffected.  Against a 4-deep group
+    whose fourth row is another user — the same program — they match
+    bit for bit; against the singletons, to fp32 rounding."""
     _, _, _, ex = live
-    metas = [_meta(50 + i, 70 + 7 * i) for i in range(3)]
+    metas = [_meta(50 + i, 70 + 7 * i) for i in range(4)]
     psis = [ex.pre_infer(m)[0] for m in metas]
     singles = [np.asarray(ex.rank_cached(m, p)[0])[0]
                for m, p in zip(metas, psis)]
-    scores, _ = ex.rank_group([_work(m, p) for m, p in zip(metas, psis)])
+    work = [_work(m, p) for m, p in zip(metas, psis)]
+    scores, _ = ex.rank_group(work[:3])
     assert len(scores) == 3                   # pad row sliced off
-    for got, want in zip(scores, singles):
-        np.testing.assert_array_equal(np.asarray(got), want)
+    full, _ = ex.rank_group(work)
+    for got, other, want in zip(scores, full, singles):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(other))
+        np.testing.assert_allclose(np.asarray(got), want, **REORDER_TOL)
 
 
 # ---------------------------------------------------------------------------
@@ -218,8 +235,9 @@ def test_aggregator_take_leaves_overflow_queued():
 
 def test_runtime_drives_batched_executor_end_to_end(live):
     """A burst of same-bucket users through the full relay: batches form,
-    every admitted request scores identically to an out-of-band
-    per-request call, and the latency invariant survives batching."""
+    every admitted request scores as an out-of-band per-request call
+    does (to fp32 rounding: a batched launch is another program), and
+    the latency invariant survives batching."""
     _, _, _, ex = live
     cfg = relay_config(
         trigger=TriggerConfig(n_instances=2, r2=0.5,
@@ -251,8 +269,8 @@ def test_runtime_drives_batched_executor_end_to_end(live):
             want, _ = ex.rank_cached(meta, psi)
         else:
             want, _ = ex.rank_full(meta)
-        np.testing.assert_array_equal(np.asarray(r.scores),
-                                      np.asarray(want)[0])
+        np.testing.assert_allclose(np.asarray(r.scores),
+                                   np.asarray(want)[0], **REORDER_TOL)
 
 
 def test_batch_grid_never_exceeds_max_batch(live):

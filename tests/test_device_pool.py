@@ -218,9 +218,9 @@ def test_page_launch_args_refuses_truncation():
     table = np.arange(8, dtype=np.int32).reshape(4, 2)  # 2 pages/slab
     psi = PagedPsi(table, 2 * PT, LAYOUT, buf)
     with pytest.raises(ValueError, match="truncation"):
-        _page_launch_args(jnp, [psi], 1)
+        _page_launch_args(jnp.asarray, [psi], 1)
     # the boundary itself (n == bucket) is fine
-    _page_launch_args(jnp, [psi], 2)
+    _page_launch_args(jnp.asarray, [psi], 2)
 
 
 # ---------------------------------------------------------------------------
@@ -339,3 +339,48 @@ def test_live_rank_group_widens_bucket_past_prefix(live):
     scores, _ = ex.rank_group(group)
     assert np.asarray(solo).tobytes() == np.asarray(scores[0]).tobytes()
     hbm.release_value(psi)
+
+
+# ---------------------------------------------------------------------------
+# placement on a device other than the default one
+# ---------------------------------------------------------------------------
+
+_PLACEMENT_PROBE = r"""
+import sys
+sys.path.insert(0, {src!r})
+import jax
+import numpy as np
+from repro.core import DevicePagePool
+from repro.core.paging import device_zeros
+
+dev = jax.devices()[3]
+host = np.arange(5 * 8 * 2 * 3, dtype=np.float32).reshape(5, 8, 2, 3)
+with jax.transfer_guard_device_to_device("disallow"):
+    z = device_zeros((4, 8, 6), np.float32, dev)
+    pool = DevicePagePool(4, host[0].nbytes)
+    pool.scatter([1, 2], host, device=dev)
+assert z.devices() == {{dev}} and not np.asarray(z).any()
+assert pool.device_buffer.devices() == {{dev}}
+got = np.asarray(pool.device_buffer)
+assert (got[1:3] == host[1:3].reshape(2, 8, 6)).all() and not got[0].any()
+print("ok")
+"""
+
+
+def test_pool_fills_on_its_own_device():
+    """A pool bound to a non-default device is created and scattered
+    there without passing through the default device: ``jnp.zeros(...,
+    device=d)`` fills on the default device and copies, which for a
+    chip-sized pool ran the default chip out of memory.  Runs in a
+    child with four host devices (this process keeps one)."""
+    import os
+    import subprocess
+    import sys
+    src = os.path.join(os.path.dirname(os.path.dirname(__file__)), "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    res = subprocess.run(
+        [sys.executable, "-c", _PLACEMENT_PROBE.format(src=src)],
+        capture_output=True, text=True, env=env, timeout=120)
+    assert res.returncode == 0, res.stderr
+    assert res.stdout.strip() == "ok"

@@ -315,9 +315,15 @@ def test_ops_wrappers_model_layout():
                                             for t in (q, k, v))), 1, 2)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=3e-4, rtol=3e-4)
-    # odd sizes fall back to the oracle path without error
-    qo, ko, vo = (_mk((B, 100, H, D), jnp.float32) for _ in range(3))
-    assert ops.hstu_attention(qo, ko, vo).shape == (B, 100, H, D)
+    # a shape the tiling cannot serve raises: no silent oracle fallback
+    qo, ko, vo = (_mk((B, 300, H, D), jnp.float32) for _ in range(3))
+    with pytest.raises(ValueError, match="no kernel tiling"):
+        ops.hstu_attention(qo, ko, vo)
+    with pytest.raises(ValueError, match="no kernel tiling"):
+        ops.rank_attention(qo[:, :64], ko, vo, n_prefix=236, n_incr=32)
+    kd = _mk((B, 600, 1, D), jnp.float32)      # 600 % 512 != 0
+    with pytest.raises(ValueError, match="no kernel tiling"):
+        ops.cache_decode_attention(qo[:, :1], kd, kd)
 
 
 @pytest.mark.parametrize("H,P,N", [(4, 64, 64), (2, 128, 32), (8, 64, 16)])
