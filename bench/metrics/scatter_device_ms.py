@@ -1,0 +1,8 @@
+"""Device milliseconds per page-pool scatter (insert or reload) in the
+traced window."""
+
+from bench.lib.readings import device_ms_per_launch
+
+
+def read(run):
+    return device_ms_per_launch(run, "scatter")
