@@ -32,6 +32,7 @@ from .runtime import (ClusterConfig, InstanceRuntime, PipelineConfig, Record,
                       RelayConfig, RelayRuntime, as_relay_config,
                       relay_config)
 from .service import RelayGRService, ServiceConfig
+from .tracing import OFF, Span, Tracer
 from .trigger import SequenceAwareTrigger, TriggerConfig
 from .types import (HASH_KEY, CacheState, HitKind, RankResult, Request,
                     Stage, UserMeta)

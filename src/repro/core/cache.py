@@ -413,6 +413,29 @@ class PagedHBMStore(HBMCacheStore):
         self.buffer = np.zeros(
             (self.pool.n_pages + 1, self.layout.page_tokens, H, D), k.dtype)
 
+    @property
+    def tracer(self):
+        return self.pool.tracer
+
+    @tracer.setter
+    def tracer(self, tracer) -> None:
+        self.pool.tracer = tracer
+
+    def _stage(self, table: np.ndarray, value: Any, t0: int = 0) -> None:
+        """Slice dense psi into the host page mirror: the pages of
+        ``table`` from token ``t0`` on.  A value still on the device is
+        copied to the host first (``d2h_bytes``)."""
+        pulled = sum(kv_nbytes(a) for a in value
+                     if not isinstance(a, np.ndarray))
+        written = (table[:, t0 // self.layout.page_tokens:].size
+                   * self.layout.page_bytes)
+        with self.tracer.span("window.stage", d2h_bytes=pulled,
+                              mirror_bytes=written):
+            slice_into_pages(self.buffer, table, value,
+                             self.layout.page_tokens, t0=t0)
+        self.pool.h2d["d2h_bytes"] += pulled
+        self.pool.h2d["mirror_bytes"] += written
+
     def _land_pages(self, pages) -> None:
         """Route freshly staged pages to the device-resident pool —
         every write path (fresh insert, resumed reload, handoff
@@ -420,11 +443,17 @@ class PagedHBMStore(HBMCacheStore):
         device mirror can never miss a page a launch may reference."""
         if self.buffer is None:
             return                          # sim mode: no page data
+        if self.device_hooks is None \
+                and not isinstance(self.pool, DevicePagePool):
+            return                          # host pool: nothing to land
         pages = [int(p) for p in pages]
-        if self.device_hooks is not None:
-            self.device_hooks.insert_pages(self.pool, pages, self.buffer)
-        elif isinstance(self.pool, DevicePagePool):
-            self.pool.scatter(pages, self.buffer)
+        with self.tracer.span("window.scatter", pages=len(pages),
+                              bytes=len(pages) * self.pool.page_bytes):
+            if self.device_hooks is not None:
+                self.device_hooks.insert_pages(self.pool, pages,
+                                               self.buffer)
+            else:
+                self.pool.scatter(pages, self.buffer)
 
     def _free_pages(self, pages) -> None:
         """Single exit turnstile for page frees (through the executor
@@ -501,8 +530,7 @@ class PagedHBMStore(HBMCacheStore):
             prefix_len=tokens, tokens_resident=tokens, page_table=table,
             spans=tuple(spans) if spans else None, tenant=int(tenant))
         if self.buffer is not None and _is_kv_pytree(value):
-            slice_into_pages(self.buffer, table, value,
-                             self.layout.page_tokens)
+            self._stage(table, value)
             self._land_pages(table.reshape(-1))
             entry.value = PagedPsi(table, tokens, self.layout, self.buffer,
                                    spans=entry.spans, pool=self.pool)
@@ -536,9 +564,7 @@ class PagedHBMStore(HBMCacheStore):
                                axis=1)
         entry.page_table = table
         if self.buffer is not None and _is_kv_pytree(value):
-            t0 = pps_res * self.layout.page_tokens
-            slice_into_pages(self.buffer, table, value,
-                             self.layout.page_tokens, t0=t0)
+            self._stage(table, value, t0=pps_res * self.layout.page_tokens)
             # partial-reload resume: only the missing TAIL pages move
             # over the link — the resident head never re-ships
             self._land_pages(fresh.reshape(-1))

@@ -51,6 +51,7 @@ from .cache import kv_nbytes
 from .costmodel import GRCostModel
 from .paging import (DevicePagePool, PageLayout, PagedPsi, ceil_div,
                      device_pages, device_zeros)
+from .tracing import OFF
 from .types import UserMeta
 
 
@@ -129,6 +130,11 @@ def _page_launch_args(put, psis: Sequence[PagedPsi], np_bucket: int):
             pool.h2d["launch_reships"] += 1
             pool.h2d["reshipped_bytes"] += int(buf.nbytes)
     return launch_buf, put(np.stack(rows))
+
+
+def _table_bytes(psis: Sequence[PagedPsi], np_bucket: int) -> int:
+    """Bytes of the launch page table ``_page_launch_args`` puts."""
+    return len(psis) * psis[0].layout.slabs * np_bucket * 4
 
 
 def _gather_psi(jnp, buf, tables, heads: int):
@@ -258,9 +264,29 @@ class SimExecutor:
         return outs, self.cost.batched_rank_ms(per, bucket=bucket)
 
 
+RANK_COUNTERS = ("rank_launches", "rank_rows", "rank_pad_rows",
+                 "rank_tokens_launched", "rank_tokens_real")
+
+
+def _launch_args(kind: str, rows: Sequence, pad_rows: int, width: int
+                 ) -> dict:
+    """A launch span's arguments; ``rows`` are the real rows' metas or
+    pending ranks."""
+    return {"kind": kind, "rows": len(rows), "pad_rows": pad_rows,
+            "bucket": width, "lens": [int(r.prefix_len) for r in rows],
+            "uids": [int(r.user_id) for r in rows]}
+
+
 @register_executor("live")
 class LiveExecutor:
-    """Runs the real HSTU backbone with jitted prefill / rank steps."""
+    """Runs the real HSTU backbone with jitted prefill / rank steps.
+
+    ``counters`` ledgers the rank launches: real and padding rows, and
+    prefix tokens launched (rows x the launch's prefix width) against
+    the real prefix lengths.  ``tracer`` (set by the owning runtime)
+    spans each launch and its input building, puts and wait."""
+
+    tracer = OFF
 
     def __init__(self, model, params, store,
                  cost: Optional[GRCostModel] = None, page_tokens: int = 0,
@@ -290,20 +316,29 @@ class LiveExecutor:
         # THIS model's psi, not the (possibly full-scale) cost model's
         self.page_layout = (PageLayout.from_model_config(
             model.cfg, page_tokens) if page_tokens else None)
-        self._prefill = jax.jit(
-            lambda p, toks: model.prefill(p, {"tokens": toks}))
-        self._rank = jax.jit(
-            lambda p, kv, incr, items: model.rank_with_cache(
-                p, kv, incr, items))
-        self._rank_full = jax.jit(
-            lambda p, pref, incr, items: model.full_rank(
-                p, pref, incr, items))
+        self.counters = dict.fromkeys(RANK_COUNTERS, 0)
+
+        # named, so the device operations in a trace name their program
+        def prefill(p, toks):
+            return model.prefill(p, {"tokens": toks})
+
+        def rank_cached(p, kv, incr, items):
+            return model.rank_with_cache(p, kv, incr, items)
+
+        def rank_full(p, pref, incr, items):
+            return model.full_rank(p, pref, incr, items)
+
         # paged consumption: psi gathered from the page pool inside the
         # jitted launch (device-side gather; no host re-materialization)
-        self._rank_pages = jax.jit(
-            lambda p, buf, tables, incr, items: model.rank_with_cache(
-                p, _gather_psi(self._jax.numpy, buf, tables,
-                               model.cfg.n_heads), incr, items))
+        def rank_pages(p, buf, tables, incr, items):
+            return model.rank_with_cache(
+                p, _gather_psi(jax.numpy, buf, tables, model.cfg.n_heads),
+                incr, items)
+
+        self._prefill = jax.jit(prefill)
+        self._rank = jax.jit(rank_cached)
+        self._rank_full = jax.jit(rank_full)
+        self._rank_pages = jax.jit(rank_pages)
 
     def _round(self, n: int, m: int = 64) -> int:
         return max(m, (n + m - 1) // m * m)  # bucketed shapes: few recompiles
@@ -311,6 +346,15 @@ class LiveExecutor:
     def _put(self, x):
         """Host array -> this executor's device."""
         return self._jax.device_put(x, self.device)
+
+    def _count_rank(self, rows: Sequence, pad_rows: int, width: int
+                    ) -> None:
+        c = self.counters
+        c["rank_launches"] += 1
+        c["rank_rows"] += len(rows)
+        c["rank_pad_rows"] += pad_rows
+        c["rank_tokens_launched"] += (len(rows) + pad_rows) * width
+        c["rank_tokens_real"] += sum(int(r.prefix_len) for r in rows)
 
     def _pad_segments(self, kv, meta: UserMeta):
         """Append the segmented entry's span slots to live psi: one
@@ -335,39 +379,77 @@ class LiveExecutor:
         return tuple(pad(a) for a in kv)
 
     def pre_infer(self, meta: UserMeta) -> Tuple[Any, int, float]:
+        tr = self.tracer
         n = self._round(meta.prefix_len)
-        toks = self._put(
-            np.resize(self.store.long_term(meta.user_id), n)[None, :])
-        t0 = time.perf_counter()
-        _, kv = self._prefill(self.params, toks)
-        kv = self._jax.block_until_ready(kv)
-        ms = (time.perf_counter() - t0) * 1e3
-        kv = self._pad_segments(kv, meta)
+        with tr.span("exec.prefill",
+                     **_launch_args("prefill", [meta], 0, n)):
+            with tr.span("exec.prepare"):
+                toks = np.resize(self.store.long_term(meta.user_id),
+                                 n)[None, :]
+            with tr.span("exec.put", bytes=toks.nbytes):
+                toks = self._put(toks)
+            t0 = time.perf_counter()
+            with tr.span("exec.wait"):
+                _, kv = self._prefill(self.params, toks)
+                kv = self._jax.block_until_ready(kv)
+            ms = (time.perf_counter() - t0) * 1e3
+            kv = self._pad_segments(kv, meta)
         return kv, kv_nbytes(kv), ms
 
+    def _launch_psi(self, psi):
+        """The cached psi a per-request launch ranks with."""
+        return psi
+
     def rank_cached(self, meta: UserMeta, psi) -> Tuple[Any, float]:
-        incr = self._put(self.store.short_term(meta.user_id)[None, :])
-        items = self._put(self.store.candidates(meta.user_id)[None, :])
-        t0 = time.perf_counter()
-        if isinstance(psi, PagedPsi):
-            buf, tables = _page_launch_args(self._put, [psi],
-                                            _pages_of(psi.n_tokens, psi))
-            scores = self._rank_pages(self.params, buf, tables, incr, items)
-        else:
-            scores = self._rank(self.params, psi, incr, items)
-        scores.block_until_ready()
+        tr = self.tracer
+        psi = self._launch_psi(psi)
+        paged = isinstance(psi, PagedPsi)
+        width = (_pages_of(psi.n_tokens, psi) * psi.layout.page_tokens
+                 if paged else int(psi[0].shape[2]))
+        self._count_rank([meta], 0, width)
+        with tr.span("exec.rank", **_launch_args("cached", [meta], 0,
+                                                 width)):
+            incr, items = self._request_inputs(meta)
+            t0 = time.perf_counter()
+            if paged:
+                npb = _pages_of(psi.n_tokens, psi)
+                with tr.span("exec.put", bytes=_table_bytes([psi], npb)):
+                    buf, tables = _page_launch_args(self._put, [psi], npb)
+            with tr.span("exec.wait"):
+                if paged:
+                    scores = self._rank_pages(self.params, buf, tables,
+                                              incr, items)
+                else:
+                    scores = self._rank(self.params, psi, incr, items)
+                scores.block_until_ready()
         return scores, (time.perf_counter() - t0) * 1e3
 
     def rank_full(self, meta: UserMeta) -> Tuple[Any, float]:
+        tr = self.tracer
         n = self._full_pad(meta.prefix_len)
-        pref = self._put(
-            np.resize(self.store.long_term(meta.user_id), n)[None, :])
-        incr = self._put(self.store.short_term(meta.user_id)[None, :])
-        items = self._put(self.store.candidates(meta.user_id)[None, :])
-        t0 = time.perf_counter()
-        scores = self._rank_full(self.params, pref, incr, items)
-        scores.block_until_ready()
+        self._count_rank([meta], 0, n)
+        with tr.span("exec.rank", **_launch_args("full", [meta], 0, n)):
+            with tr.span("exec.prepare"):
+                pref = np.resize(self.store.long_term(meta.user_id),
+                                 n)[None, :]
+            with tr.span("exec.put", bytes=pref.nbytes):
+                pref = self._put(pref)
+            incr, items = self._request_inputs(meta)
+            t0 = time.perf_counter()
+            with tr.span("exec.wait"):
+                scores = self._rank_full(self.params, pref, incr, items)
+                scores.block_until_ready()
         return scores, (time.perf_counter() - t0) * 1e3
+
+    def _request_inputs(self, meta: UserMeta):
+        """One request's incremental tokens and candidates, on the
+        device."""
+        tr = self.tracer
+        with tr.span("exec.prepare"):
+            incr = self.store.short_term(meta.user_id)[None, :]
+            items = self.store.candidates(meta.user_id)[None, :]
+        with tr.span("exec.put", bytes=incr.nbytes + items.nbytes):
+            return self._put(incr), self._put(items)
 
     def _full_pad(self, n: int) -> int:
         """Padded prefix length for the full-inference fallback."""
@@ -440,12 +522,11 @@ class BatchedLiveExecutor(LiveExecutor):
 
     # --- per-request paths on the bucket grid -------------------------------
 
-    def rank_cached(self, meta: UserMeta, psi) -> Tuple[Any, float]:
+    def _launch_psi(self, psi):
         if isinstance(psi, PagedPsi):
-            # page tables already pad to the page-count bucket in super
-            return super().rank_cached(meta, psi)
-        psi = pad_psi(self._jax.numpy, psi, bucket_of(psi[0].shape[2]))
-        return super().rank_cached(meta, psi)
+            # page tables already pad to the page-count bucket at launch
+            return psi
+        return pad_psi(self._jax.numpy, psi, bucket_of(psi[0].shape[2]))
 
     def _full_pad(self, n: int) -> int:
         return bucket_of(n)
@@ -465,19 +546,14 @@ class BatchedLiveExecutor(LiveExecutor):
         """Execute a compatible group as ONE jitted call.
         Returns (per-member scores, measured group wall ms)."""
         jnp = self._jax.numpy
+        tr = self.tracer
         n = len(group)
         bucket = bucket_of(max(w.prefix_len for w in group))
         pad_rows = self._batch_grid(n) - n
         rows = list(group) + [group[0]] * pad_rows
-        incr = np.stack([w.incr if w.incr is not None
-                         else self.store.short_term(w.user_id)
-                         for w in rows])
-        items = np.stack([w.items if w.items is not None
-                          else self.store.candidates(w.user_id)
-                          for w in rows])
-        t0 = time.perf_counter()
-        incr, items = self._put(incr), self._put(items)
-        if isinstance(group[0].psi, PagedPsi):
+        paged = isinstance(group[0].psi, PagedPsi)
+        width = bucket
+        if paged:
             # rank_with_pages: ONE launch keyed (page-count bucket,
             # batch grid); K/V stay in the page pool and are gathered
             # through the stacked page tables inside the jit.  The
@@ -489,19 +565,47 @@ class BatchedLiveExecutor(LiveExecutor):
             pt = group[0].psi.layout.page_tokens
             npb = max([page_bucket(bucket, pt)]
                       + [_pages_of(w.psi.n_tokens, w.psi) for w in rows])
-            buf, tables = _page_launch_args(self._put,
-                                            [w.psi for w in rows], npb)
-            scores = self._rank_pages(self.params, buf, tables, incr, items)
-        elif group[0].psi is not None:        # homogeneous by aggregator key
-            kv = stack_psi(jnp, [w.psi for w in rows], bucket)
-            scores = self._rank(self.params, kv, incr, items)
-        else:
-            pref = self._put(np.stack([
-                np.resize(self.store.long_term(w.user_id), bucket)
-                for w in rows]))
-            scores = self._rank_full(self.params, pref, incr, items)
-        scores.block_until_ready()
-        ms = (time.perf_counter() - t0) * 1e3
+            width = npb * pt
+        kind = "full" if group[0].psi is None else "cached"
+        self._count_rank(group, pad_rows, width)
+        with tr.span("exec.rank",
+                     **_launch_args(kind, group, pad_rows, width)):
+            with tr.span("exec.prepare"):
+                incr = np.stack([w.incr if w.incr is not None
+                                 else self.store.short_term(w.user_id)
+                                 for w in rows])
+                items = np.stack([w.items if w.items is not None
+                                  else self.store.candidates(w.user_id)
+                                  for w in rows])
+            t0 = time.perf_counter()
+            with tr.span("exec.put", bytes=incr.nbytes + items.nbytes):
+                incr, items = self._put(incr), self._put(items)
+            if paged:
+                with tr.span("exec.put",
+                             bytes=_table_bytes([w.psi for w in rows], npb)):
+                    buf, tables = _page_launch_args(
+                        self._put, [w.psi for w in rows], npb)
+            elif group[0].psi is not None:   # homogeneous by aggregator key
+                with tr.span("exec.prepare"):
+                    kv = stack_psi(jnp, [w.psi for w in rows], bucket)
+            else:
+                with tr.span("exec.prepare"):
+                    pref = np.stack([
+                        np.resize(self.store.long_term(w.user_id), bucket)
+                        for w in rows])
+                with tr.span("exec.put", bytes=pref.nbytes):
+                    pref = self._put(pref)
+            with tr.span("exec.wait"):
+                if paged:
+                    scores = self._rank_pages(self.params, buf, tables,
+                                              incr, items)
+                elif group[0].psi is not None:
+                    scores = self._rank(self.params, kv, incr, items)
+                else:
+                    scores = self._rank_full(self.params, pref, incr,
+                                             items)
+                scores.block_until_ready()
+            ms = (time.perf_counter() - t0) * 1e3
         return [scores[i] for i in range(n)], ms
 
     def pre_infer_group(self, metas: Sequence[UserMeta]
@@ -513,20 +617,27 @@ class BatchedLiveExecutor(LiveExecutor):
         first member, and each member's psi slice — rows are
         independent under batched compute — is bit-identical to the psi
         its own per-request ``pre_infer`` call would have produced."""
+        tr = self.tracer
         n = self._round(max(m.prefix_len for m in metas))
-        rows = list(metas)
-        rows += [metas[0]] * (self._batch_grid(len(metas)) - len(metas))
-        toks = np.stack([np.resize(self.store.long_term(m.user_id), n)
-                         for m in rows])
-        t0 = time.perf_counter()
-        _, kv = self._prefill(self.params, self._put(toks))
-        kv = self._jax.block_until_ready(kv)
-        ms = (time.perf_counter() - t0) * 1e3
-        outs = []
-        for i in range(len(metas)):
-            psi = tuple(a[:, i:i + 1] for a in kv)   # (L, 1, n, H, D)
-            psi = self._pad_segments(psi, metas[i])
-            outs.append((psi, kv_nbytes(psi)))
+        pad_rows = self._batch_grid(len(metas)) - len(metas)
+        rows = list(metas) + [metas[0]] * pad_rows
+        with tr.span("exec.prefill",
+                     **_launch_args("prefill", metas, pad_rows, n)):
+            with tr.span("exec.prepare"):
+                toks = np.stack([np.resize(self.store.long_term(m.user_id),
+                                           n) for m in rows])
+            t0 = time.perf_counter()
+            with tr.span("exec.put", bytes=toks.nbytes):
+                toks = self._put(toks)
+            with tr.span("exec.wait"):
+                _, kv = self._prefill(self.params, toks)
+                kv = self._jax.block_until_ready(kv)
+            ms = (time.perf_counter() - t0) * 1e3
+            outs = []
+            for i in range(len(metas)):
+                psi = tuple(a[:, i:i + 1] for a in kv)   # (L, 1, n, H, D)
+                psi = self._pad_segments(psi, metas[i])
+                outs.append((psi, kv_nbytes(psi)))
         return outs, ms
 
     # --- startup pre-warming -------------------------------------------------

@@ -43,6 +43,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .tracing import OFF
+
 
 def ceil_div(a: int, b: int) -> int:
     return -(-a // b)
@@ -86,6 +88,11 @@ class PageLayout:
                    token_bytes=cfg.n_heads * cfg.head_dim * itemsize)
 
 
+H2D_KEYS = ("bytes_scattered", "pages_scattered", "scatters",
+            "launch_reships", "reshipped_bytes", "d2h_bytes",
+            "mirror_bytes", "materialized_bytes")
+
+
 class PagePool:
     """Free-list allocator over a fixed number of pages.
 
@@ -96,6 +103,8 @@ class PagePool:
     the free list (a freed-but-pinned zombie is still live: it occupies
     pool capacity until the pinning launch releases it).
     """
+
+    tracer = OFF            # the owning window's (``PagedHBMStore.tracer``)
 
     def __init__(self, n_pages: int, page_bytes: int):
         self.n_pages = int(n_pages)
@@ -109,10 +118,12 @@ class PagePool:
         # side counts every page landed in the device-resident buffer
         # (``bytes_scattered`` == bytes of freshly written pages) and
         # ``launch_reships`` stays 0; on a host-buffer pool the launch
-        # path counts each whole-pool re-ship instead.
-        self.h2d = {"bytes_scattered": 0, "pages_scattered": 0,
-                    "scatters": 0, "launch_reships": 0,
-                    "reshipped_bytes": 0}
+        # path counts each whole-pool re-ship instead.  The host side of
+        # psi's trip is counted too: ``d2h_bytes`` pulled out of device
+        # arrays to stage them, ``mirror_bytes`` of pages written into
+        # the host page buffer, ``materialized_bytes`` of dense host
+        # copies gathered out of the pool (spill, evict, handoff).
+        self.h2d = dict.fromkeys(H2D_KEYS, 0)
 
     @property
     def free_pages(self) -> int:
@@ -193,8 +204,11 @@ def _scatter_jit():
     global _SCATTER_JIT
     if _SCATTER_JIT is None:
         import jax
-        _SCATTER_JIT = jax.jit(lambda buf, idx, vals: buf.at[idx].set(vals),
-                               donate_argnums=(0,))
+
+        def pool_scatter(buf, idx, vals):
+            return buf.at[idx].set(vals)
+
+        _SCATTER_JIT = jax.jit(pool_scatter, donate_argnums=(0,))
     return _SCATTER_JIT
 
 
@@ -309,13 +323,19 @@ class PagedPsi:
     def materialize(self) -> Any:
         assert self.buffer is not None, "sim-mode psi has no page data"
         slabs, np_ = self.table.shape
-        L = slabs // 2
-        # (slabs, n_pages, pt, H, D) -> (slabs, P_padded, H, D)
-        flat = self.buffer[self.table].reshape(
-            slabs, np_ * self.layout.page_tokens, *self.buffer.shape[2:])
-        k = flat[0::2][:, None]             # (L, 1, P, H, D)
-        v = flat[1::2][:, None]
-        return (k.copy(), v.copy())
+        nbytes = slabs * np_ * self.layout.page_bytes
+        pool = self.pool
+        tracer = pool.tracer if pool is not None else OFF
+        with tracer.span("window.materialize", bytes=nbytes):
+            # (slabs, n_pages, pt, H, D) -> (slabs, P_padded, H, D)
+            flat = self.buffer[self.table].reshape(
+                slabs, np_ * self.layout.page_tokens, *self.buffer.shape[2:])
+            k = flat[0::2][:, None]             # (L, 1, P, H, D)
+            v = flat[1::2][:, None]
+            out = (k.copy(), v.copy())
+        if pool is not None:
+            pool.h2d["materialized_bytes"] += nbytes
+        return out
 
 
 def slice_into_pages(buffer: np.ndarray, table: np.ndarray, value: Any,

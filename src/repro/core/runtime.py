@@ -62,7 +62,6 @@ import numpy as np
 
 from repro.serving.batching import BatchAggregator, BatchingConfig, \
     PendingRank, prefill_grid
-from repro.serving.metrics import SLOTracker
 
 from .cache import HBMCacheStore, make_hbm_store
 from .clock import Clock, VirtualClock, WallClock
@@ -70,10 +69,11 @@ from .coldstore import ColdStore, ColdStoreConfig
 from .costmodel import GRCostModel
 from .executors import Executor, get_executor
 from .expander import DRAMExpander, ExpanderConfig
-from .paging import DevicePagePool, PageLayout
+from .paging import H2D_KEYS, DevicePagePool, PageLayout
 from .policies import make_expander, make_router, make_trigger
 from .topology import (ClusterTopology, Host, make_prefill_hosts,
                        stripe_hosts)
+from .tracing import OFF, Tracer
 from .trigger import TriggerConfig
 from .types import HitKind, RankResult, Request, UserMeta, reuse_spans
 
@@ -254,6 +254,24 @@ class Record:
         return (self.t_done - self.t_arrival) * 1e3
 
 
+def _event_ids(kw: dict) -> dict:
+    """The user (or users) an event concerns, for its ``relay.event``
+    span: a stage's ``meta``, a job's request, or a group's members."""
+    job = kw.get("job")
+    if job is not None:
+        if "req" in job:
+            return {"uid": job["req"].user.user_id, "req": job["req"].req_id}
+        if "meta" in job:
+            return {"uid": job["meta"].user_id}
+        if "group" in job:
+            return {"uids": [w.user_id for w in job["group"]]}
+    if "meta" in kw:
+        return {"uid": kw["meta"].user_id}
+    if "group" in kw:
+        return {"uids": [w.user_id for w in kw["group"]]}
+    return {}
+
+
 # ---------------------------------------------------------------------------
 # ranking instance
 # ---------------------------------------------------------------------------
@@ -354,6 +372,13 @@ class InstanceRuntime:
         self.inflight_pre: set = set()
         self.user_waiters: Dict[int, List[dict]] = defaultdict(list)
         self.busy_ms = 0.0
+
+    def use_tracer(self, tracer: Tracer) -> None:
+        """Span this instance's window and executor with ``tracer``."""
+        if hasattr(self.hbm, "tracer"):
+            self.hbm.tracer = tracer
+        if hasattr(self.executor, "tracer"):
+            self.executor.tracer = tracer
 
     # --- transition kernels (shared by both drive modes) --------------------
 
@@ -543,10 +568,14 @@ class RelayRuntime:
 
     def __init__(self, cfg, cost: GRCostModel,
                  executor_factory: Optional[Callable[[str], Executor]] = None,
-                 clock: Optional[Clock] = None):
+                 clock: Optional[Clock] = None,
+                 tracer: Optional[Tracer] = None):
         self.cfg = as_relay_config(cfg)
         self.cost = cost
         self.clock: Clock = clock if clock is not None else VirtualClock()
+        # spans and per-request marks (repro.core.tracing); off unless
+        # the caller passes a tracer that is on
+        self.tracer: Tracer = tracer if tracer is not None else OFF
         cl = self.cfg.cluster
         # multi-tenant serving: tenants > 1 partitions every memory tier
         # into equal byte shares and layers per-tenant admission buckets
@@ -709,7 +738,6 @@ class RelayRuntime:
         self.records: List[Record] = []
         self._seq = itertools.count()
         self._req_ids = itertools.count()
-        self.slo = SLOTracker(slo_ms=self.cfg.pipeline.pipeline_slo_ms)
         self.now = 0.0
 
     # --- lifecycle transitions shared with the manual stage API ---------------
@@ -746,11 +774,19 @@ class RelayRuntime:
         heapq.heappush(self.events, (t, next(self._seq), kind, kw))
 
     def drain(self) -> None:
+        tracer = self.tracer
         while self.events:
             t, _, kind, kw = heapq.heappop(self.events)
             self.now = t
             self.clock.advance(t)
-            getattr(self, f"_on_{kind}")(t, **kw)
+            handler = getattr(self, f"_on_{kind}")
+            if tracer.on:
+                with tracer.span("relay.event", kind=kind,
+                                 late_ms=(self.clock.now() - t) * 1e3,
+                                 **_event_ids(kw)):
+                    handler(t, **kw)
+            else:
+                handler(t, **kw)
 
     def run(self, arrivals: Iterable[Tuple[float, UserMeta]]
             ) -> Dict[str, float]:
@@ -768,11 +804,19 @@ class RelayRuntime:
         self.drain()
         return box[0]
 
+    def use_tracer(self, tracer: Tracer) -> None:
+        """Span this runtime, its instances' windows and executors with
+        ``tracer`` from now on (``OFF`` turns tracing off again)."""
+        self.tracer = tracer
+        for inst in self.instances.values():
+            inst.use_tracer(tracer)
+
     def _adopt(self, inst: InstanceRuntime) -> InstanceRuntime:
         # instances hot-swapped in by churn tests/deployments get wired
         # to this loop on first contact
         if inst.loop is not self:
             inst.loop = self
+            inst.use_tracer(self.tracer)
         return inst
 
     def _tenant_quota_map(self, budget: float) -> Optional[Dict[int, int]]:
@@ -810,6 +854,7 @@ class RelayRuntime:
         inst = InstanceRuntime(icfg, self._factory(name),
                                expander=self.host_expanders.get(host))
         inst.loop = self
+        inst.use_tracer(self.tracer)
         if self.cold_enabled and role != "prefill":
             # DRAM LRU evictees demote down to the host's cold store
             # (asynchronously, priced on the host cold link) instead of
@@ -1439,6 +1484,7 @@ class RelayRuntime:
                          sink=None) -> None:
         req, target = self.bind_rank(meta, t)
         rec.t_rank_arrival = t
+        self.tracer.mark(req.req_id, "due", t)
         inst = self._adopt(self.instances[target])
         inst.enqueue({"kind": "rank", "req": req, "rec": rec, "sink": sink}, t)
 
@@ -1681,7 +1727,9 @@ class RelayRuntime:
         rec: Record = job["rec"]
         comp = {"pre": rec.pre_ms, "load": rec.load_ms, "rank": 0.0,
                 "queue": rec.queue_ms}
+        self._mark_launch([job], "launch")
         result = inst.exec_rank(job["req"], action, entry, comp, t)
+        self._mark_launch([job], "launched")
         rec.rank_ms = comp["rank"]
         rec.hit = result.hit.value
         if result.hit != HitKind.MISS_FALLBACK:
@@ -1763,7 +1811,10 @@ class RelayRuntime:
         latency_ms == sum(components) == rank-stage wall time."""
         for w in group:
             w.payload["rec"].queue_ms += (t - w.enqueued_at) * 1e3
+        jobs = [w.payload for w in group]
+        self._mark_launch(jobs, "launch")
         scores, group_ms = inst.executor.rank_group(group)
+        self._mark_launch(jobs, "launched")
         for w in group:
             inst.hbm.release_value(w.psi)  # unpin pages held since classify
         inst.busy_ms += group_ms
@@ -1786,21 +1837,40 @@ class RelayRuntime:
                        group: List[PendingRank],
                        results: List[RankResult]) -> None:
         for w, result in zip(group, results):
-            rec: Record = w.payload["rec"]
-            e = inst.hbm.consume(result.user_id)
-            if e is not None and inst.expander.cfg.dram_budget_bytes > 0:
-                if inst.expander.spill(dataclasses.replace(e)):
-                    inst.stats["spills"] += 1
-                    e.dram_backed = True   # eligible for partial eviction
-            rec.t_done = t
-            rec.rank_stage_ms = rec.queue_ms + rec.load_ms + rec.rank_ms
-            self.records.append(rec)
-            self.slo.observe(now=t, e2e_ms=rec.e2e_ms, hit=rec.hit,
-                             components=result.components)
-            sink = w.payload.get("sink")
-            if sink is not None:
-                sink(result)
+            self._complete_rank(t, inst, w.payload, result)
         inst.release_slot(t)
+
+    def _mark_launch(self, jobs: List[dict], name: str) -> None:
+        if self.tracer.on:
+            now = self.clock.now()
+            for job in jobs:
+                self.tracer.mark(job["req"].req_id, name, now)
+
+    def _complete_rank(self, t: float, inst: InstanceRuntime, job: dict,
+                       result: RankResult) -> None:
+        """A rank's scores are in: spill its consumed psi to the DRAM
+        tier (a proactive copy for short-term cross-request reuse),
+        record it and hand the scores to the sink."""
+        tracer = self.tracer
+        rec: Record = job["rec"]
+        e = inst.hbm.consume(result.user_id)
+        if e is not None and inst.expander.cfg.dram_budget_bytes > 0:
+            with tracer.span("dram.spill", uid=result.user_id,
+                             bytes=e.nbytes):
+                spilled = inst.expander.spill(dataclasses.replace(e))
+            if spilled:
+                inst.stats["spills"] += 1
+                e.dram_backed = True       # eligible for partial eviction
+        rec.t_done = t
+        rec.rank_stage_ms = rec.queue_ms + rec.load_ms + rec.rank_ms
+        self.records.append(rec)
+        sink = job.get("sink")
+        if sink is not None:
+            if tracer.on:
+                tracer.mark(result.req_id, "sink", self.clock.now())
+            with tracer.span("relay.sink", req=result.req_id,
+                             uid=result.user_id):
+                sink(result)
 
     # --- completions -------------------------------------------------------------
 
@@ -2011,21 +2081,7 @@ class RelayRuntime:
 
     def _on_rank_done(self, t: float, inst: InstanceRuntime, job: dict,
                       result: RankResult) -> None:
-        rec: Record = job["rec"]
-        e = inst.hbm.consume(result.user_id)
-        if e is not None and inst.expander.cfg.dram_budget_bytes > 0:
-            # proactive spill copy for short-term cross-request reuse
-            if inst.expander.spill(dataclasses.replace(e)):
-                inst.stats["spills"] += 1
-                e.dram_backed = True       # eligible for partial eviction
-        rec.t_done = t
-        rec.rank_stage_ms = rec.queue_ms + rec.load_ms + rec.rank_ms
-        self.records.append(rec)
-        self.slo.observe(now=t, e2e_ms=rec.e2e_ms, hit=rec.hit,
-                         components=result.components)
-        sink = job.get("sink")
-        if sink is not None:
-            sink(result)
+        self._complete_rank(t, inst, job, result)
         inst.release_slot(t)
 
     # --- metrics -------------------------------------------------------------------
@@ -2148,15 +2204,13 @@ class RelayRuntime:
                                                    "live": s.live_count}
                                for h, s in self._orphan_cold.items()}}},
                "cold_links": {h: dict(l)
-                              for h, l in self.cold_links.items()},
-               "slo": self.slo.summary(now=self.now)}
+                              for h, l in self.cold_links.items()}}
         # host->device traffic ledger, summed over the paged windows:
         # scatter-on-insert bytes vs whole-pool launch re-ships.  On
         # the device-pool path ``launch_reships`` MUST read 0 and
         # ``bytes_scattered`` equals the freshly inserted page bytes
         # (the acceptance surface of the device-resident pool).
-        h2d = {"bytes_scattered": 0, "pages_scattered": 0, "scatters": 0,
-               "launch_reships": 0, "reshipped_bytes": 0}
+        h2d = dict.fromkeys(H2D_KEYS, 0)
         device_resident = False
         inst = {}
         for name, i in self.instances.items():

@@ -24,6 +24,7 @@ from .clock import Clock, WallClock
 from .costmodel import GRCostModel
 from .runtime import (ClusterConfig, RelayConfig, RelayRuntime,
                       as_relay_config, relay_config)
+from .tracing import Tracer
 from .trigger import TriggerConfig
 from .types import RankResult, Request, UserMeta
 
@@ -56,11 +57,13 @@ class ServiceConfig:
 
 class RelayGRService:
     def __init__(self, cfg, cost: GRCostModel, executor_factory=None,
-                 clock: Optional[Clock] = None):
+                 clock: Optional[Clock] = None,
+                 tracer: Optional[Tracer] = None):
         self.cfg = as_relay_config(cfg)
         self.cost = cost
         self.runtime = RelayRuntime(self.cfg, cost, executor_factory,
-                                    clock=clock or WallClock())
+                                    clock=clock or WallClock(),
+                                    tracer=tracer)
 
     # --- adapter surface (state lives on the shared runtime) -------------------
 
@@ -88,10 +91,6 @@ class RelayGRService:
         return self.runtime.instances
 
     @property
-    def slo(self):
-        return self.runtime.slo
-
-    @property
     def special_names(self):
         return self.runtime.special
 
@@ -115,11 +114,7 @@ class RelayGRService:
     # --- stage 3: fine-grained ranking ----------------------------------------
     def on_rank(self, meta: UserMeta, now: float) -> RankResult:
         req, target = self.runtime.bind_rank(meta, now)
-        result = self.instances[target].handle_rank(req, now)
-        self.slo.observe(now=now, e2e_ms=result.latency_ms,
-                         hit=result.hit.value,
-                         components=result.components)
-        return result
+        return self.instances[target].handle_rank(req, now)
 
     # --- synchronous end-to-end (live mode / tests) ----------------------------
     def submit(self, meta: UserMeta, now: Optional[float] = None

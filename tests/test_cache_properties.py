@@ -197,7 +197,7 @@ def test_free_list_never_double_allocates(plan, pool_pages):
         pages = pool.alloc(n)
         if pages is not None:
             assert len(set(pages)) == len(pages)
-            flat = {p for ps in outstanding for p in ps}
+            flat = {p for ps, _ in outstanding for p in ps}
             assert not flat & set(pages), "double allocation"
             if pin:
                 pool.pin(pages)

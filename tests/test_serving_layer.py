@@ -1,4 +1,4 @@
-"""Batched execution + streaming metrics + stateful property tests for
+"""Batched execution + stateful property tests for
 the cache/expander interplay (hypothesis rule-based state machine)."""
 
 import dataclasses
@@ -6,7 +6,6 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 from _hyp import (RuleBasedStateMachine, given, initialize, invariant, rule,
                   settings, st)
 
@@ -15,7 +14,6 @@ from repro.core.expander import DRAMExpander, ExpanderConfig
 from repro.models import get_model
 from repro.serving.batching import (BatchAggregator, BatchedRankExecutor,
                                     BatchingConfig, PendingRank, bucket_of)
-from repro.serving.metrics import P2Quantile, SLOTracker, WindowRate
 
 RNG = np.random.default_rng(21)
 
@@ -73,49 +71,6 @@ def test_bucketing_monotone(n):
     b = bucket_of(n)
     assert b >= min(n, 32768)
     assert bucket_of(b) == b
-
-
-# ---------------------------------------------------------------------------
-# P2 quantile estimator
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("q", [0.5, 0.9, 0.99])
-def test_p2_quantile_converges(q):
-    rng = np.random.default_rng(3)
-    data = rng.exponential(10.0, size=20000)
-    est = P2Quantile(q)
-    for x in data:
-        est.add(float(x))
-    true = np.quantile(data, q)
-    assert abs(est.value - true) / true < 0.15
-
-
-def test_p2_small_samples():
-    est = P2Quantile(0.99)
-    for x in (5.0, 1.0, 3.0):
-        est.add(x)
-    assert est.value == 5.0
-
-
-def test_window_rate():
-    w = WindowRate(window_s=10.0)
-    for t in np.linspace(0, 10, 101):
-        w.mark(float(t))
-    assert w.rate(10.0) == pytest.approx(10.1, rel=0.05)
-    assert w.rate(25.0) == 0.0
-
-
-def test_slo_tracker_summary():
-    tr = SLOTracker(slo_ms=100.0)
-    for i in range(50):
-        tr.observe(now=i * 0.01, e2e_ms=50.0 + i, hit="hbm_hit",
-                   components={"rank": 10.0})
-    s = tr.summary(now=0.5)
-    assert s["n"] == 50
-    assert 0.9 < s["success_rate"] <= 1.0
-    assert s["hit_hbm_hit"] == 1.0
-    assert s["rank_p99_ms"] == pytest.approx(10.0)
 
 
 # ---------------------------------------------------------------------------
