@@ -353,15 +353,18 @@ class PagedHBMStore(HBMCacheStore):
         only the missing pages (``resumed_reloads``);
       * launches pin pages (``acquire_value``/``release_value``), so a
         deferred batched launch never reads a recycled page;
-      * in live mode the pool owns a real ``(n_pages + 1, page_tokens,
-        H, D)`` buffer (lazily shaped from the first psi; the extra
-        last row is the all-zero null page used to pad page tables to a
-        bucket) and ``PagedPsi`` handles point into it;
-      * with ``device_pool=True`` the pool is a ``DevicePagePool``:
-        the host buffer stays the staging area / host-read source, and
-        every page write additionally scatters into the device-resident
-        mirror (one donated update per insert/resume) so rank launches
-        pass the pool by reference instead of re-shipping it.
+      * in live mode the pool's data plane holds ``n_pages + 1`` pages
+        (lazily shaped from the first psi; the extra last row is the
+        all-zero null page used to pad page tables to a bucket) and
+        ``PagedPsi`` handles point into it;
+      * with ``device_pool=False`` that data plane is the host buffer
+        ``buffer``, ``(n_pages + 1, page_tokens, H, D)``, which psi is
+        sliced into page by page;
+      * with ``device_pool=True`` the pool is a ``DevicePagePool`` and
+        its device buffer is the only copy: dense psi lands there with
+        one donated update per insert/resume (``DevicePagePool.land``),
+        rank launches pass it by reference, ``materialize`` gathers it
+        back on the device, and no host buffer is ever allocated.
     """
 
     def __init__(self, budget_bytes: int, layout: PageLayout,
@@ -381,10 +384,11 @@ class PagedHBMStore(HBMCacheStore):
             {t: int(b) // layout.page_bytes
              for t, b in self.tenant_quota.items()}
             if self.tenant_quota is not None else None)
-        self.buffer: Optional[np.ndarray] = None   # lazily shaped
+        # host pool's page buffer, lazily shaped; a device pool has none
+        self.buffer: Optional[np.ndarray] = None
         # device-pool routing: when the runtime wires an executor here
         # (``InstanceRuntime``), page-data movement goes through its
-        # insert_pages/free_pages hooks; unwired device pools scatter
+        # insert_pages/free_pages hooks; unwired device pools land
         # directly.  None + host pool is the pure-host path.
         self.device_hooks = None
         # gather a dense host copy of psi when it leaves the pool, so
@@ -406,7 +410,8 @@ class PagedHBMStore(HBMCacheStore):
         return max(1, ceil_div(int(nbytes), per_token))
 
     def _ensure_buffer(self, value: Any) -> None:
-        if self.buffer is not None or not _is_kv_pytree(value):
+        if (self.buffer is not None or not _is_kv_pytree(value)
+                or isinstance(self.pool, DevicePagePool)):
             return
         k = np.asarray(value[0])
         H, D = k.shape[3], k.shape[4]
@@ -421,39 +426,33 @@ class PagedHBMStore(HBMCacheStore):
     def tracer(self, tracer) -> None:
         self.pool.tracer = tracer
 
-    def _stage(self, table: np.ndarray, value: Any, t0: int = 0) -> None:
-        """Slice dense psi into the host page mirror: the pages of
-        ``table`` from token ``t0`` on.  A value still on the device is
-        copied to the host first (``d2h_bytes``)."""
+    def _write_pages(self, table: np.ndarray, value: Any,
+                     first: int = 0) -> None:
+        """Write dense psi into the pages of ``table`` from page column
+        ``first`` on — every write path (fresh insert, resumed reload,
+        handoff re-insert, cold-promotion landing) converges here.  A
+        device pool lands the value on the device (through the
+        executor's ``insert_pages`` when wired); a host pool slices it
+        into the host buffer, pulling a device value first
+        (``d2h_bytes``)."""
+        pages = table[:, first:].reshape(-1)
+        if isinstance(self.pool, DevicePagePool):
+            if self.device_hooks is not None:
+                self.device_hooks.insert_pages(self.pool, pages, value,
+                                               table=table, first=first)
+            else:
+                self.pool.land(pages, table, value, first=first)
+            return
         pulled = sum(kv_nbytes(a) for a in value
                      if not isinstance(a, np.ndarray))
-        written = (table[:, t0 // self.layout.page_tokens:].size
-                   * self.layout.page_bytes)
+        written = pages.size * self.layout.page_bytes
         with self.tracer.span("window.stage", d2h_bytes=pulled,
                               mirror_bytes=written):
             slice_into_pages(self.buffer, table, value,
-                             self.layout.page_tokens, t0=t0)
+                             self.layout.page_tokens,
+                             t0=first * self.layout.page_tokens)
         self.pool.h2d["d2h_bytes"] += pulled
         self.pool.h2d["mirror_bytes"] += written
-
-    def _land_pages(self, pages) -> None:
-        """Route freshly staged pages to the device-resident pool —
-        every write path (fresh insert, resumed reload, handoff
-        re-insert, cold-promotion landing) converges here, so the
-        device mirror can never miss a page a launch may reference."""
-        if self.buffer is None:
-            return                          # sim mode: no page data
-        if self.device_hooks is None \
-                and not isinstance(self.pool, DevicePagePool):
-            return                          # host pool: nothing to land
-        pages = [int(p) for p in pages]
-        with self.tracer.span("window.scatter", pages=len(pages),
-                              bytes=len(pages) * self.pool.page_bytes):
-            if self.device_hooks is not None:
-                self.device_hooks.insert_pages(self.pool, pages,
-                                               self.buffer)
-            else:
-                self.pool.scatter(pages, self.buffer)
 
     def _free_pages(self, pages) -> None:
         """Single exit turnstile for page frees (through the executor
@@ -529,9 +528,8 @@ class PagedHBMStore(HBMCacheStore):
             user_id, value, need * self.layout.page_bytes, now,
             prefix_len=tokens, tokens_resident=tokens, page_table=table,
             spans=tuple(spans) if spans else None, tenant=int(tenant))
-        if self.buffer is not None and _is_kv_pytree(value):
-            self._stage(table, value)
-            self._land_pages(table.reshape(-1))
+        if _is_kv_pytree(value):
+            self._write_pages(table, value)
             entry.value = PagedPsi(table, tokens, self.layout, self.buffer,
                                    spans=entry.spans, pool=self.pool)
         self.entries[user_id] = entry
@@ -563,11 +561,10 @@ class PagedHBMStore(HBMCacheStore):
         table = np.concatenate([entry.page_table[:, :pps_res], fresh],
                                axis=1)
         entry.page_table = table
-        if self.buffer is not None and _is_kv_pytree(value):
-            self._stage(table, value, t0=pps_res * self.layout.page_tokens)
-            # partial-reload resume: only the missing TAIL pages move
-            # over the link — the resident head never re-ships
-            self._land_pages(fresh.reshape(-1))
+        if _is_kv_pytree(value):
+            # partial-reload resume: only the missing TAIL pages are
+            # written — the resident head is left as it is
+            self._write_pages(table, value, first=pps_res)
             entry.value = PagedPsi(table, entry.prefix_len, self.layout,
                                    self.buffer, spans=entry.spans,
                                    pool=self.pool)

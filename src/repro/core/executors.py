@@ -102,13 +102,15 @@ def _page_launch_args(put, psis: Sequence[PagedPsi], np_bucket: int):
 
     The pool buffer: a ``DevicePagePool`` passes its device-resident
     array by REFERENCE (zero host->device traffic per launch); a
-    host-buffer pool re-ships the whole pool, counted in the owning
-    pool's ``h2d`` ledger.  A member whose table exceeds ``np_bucket``
+    host-buffer pool re-ships its whole page buffer, counted in the
+    owning pool's ``h2d`` ledger.  A member whose table exceeds ``np_bucket``
     is an error — truncating would silently drop cached pages from the
     gather (callers widen the launch bucket to the group's largest
     member instead).  ``put`` moves a host array to the launching
     executor's device."""
-    buf = psis[0].buffer
+    pool = psis[0].pool
+    on_device = isinstance(pool, DevicePagePool)
+    buf = pool.device_buffer if on_device else psis[0].buffer
     null = buf.shape[0] - 1
     rows = []
     for psi in psis:
@@ -121,9 +123,8 @@ def _page_launch_args(put, psis: Sequence[PagedPsi], np_bucket: int):
         t = np.full((slabs, np_bucket), null, np.int32)
         t[:, :n] = psi.table
         rows.append(t.reshape(slabs // 2, 2, np_bucket))
-    pool = psis[0].pool
-    if isinstance(pool, DevicePagePool):
-        launch_buf = pool.device_view(buf)
+    if on_device:
+        launch_buf = buf
     else:
         launch_buf = put(device_pages(buf))    # O(pool bytes) per launch
         if pool is not None:
@@ -467,20 +468,27 @@ class LiveExecutor:
     # executor (the owner of the jax device), so every path that writes
     # pages — fresh insert, resumed partial reload, handoff re-insert,
     # cold-promotion landing — lands them in the device-resident pool
-    # with ONE donated scatter, and every free goes back through the
-    # same conserved free-list accounting.
+    # with ONE donated update on this executor's device, and every free
+    # goes back through the same conserved free-list accounting.
 
     def insert_pages(self, pool: DevicePagePool, pages: Sequence[int],
-                     host_buffer: np.ndarray) -> int:
-        """Scatter freshly written ``pages`` (already staged in the
-        host buffer) into the device-resident pool.  Returns the bytes
-        moved over the H2D link (== len(pages) * page_bytes)."""
-        return pool.scatter(pages, host_buffer, device=self.device)
+                     src: Any, table: Optional[np.ndarray] = None,
+                     first: int = 0) -> int:
+        """Land ``pages`` in the device-resident pool.  With ``table``,
+        ``src`` is the dense psi ``(K, V)`` and the pages of ``table``
+        from page column ``first`` on are written from it
+        (``DevicePagePool.land``); without, ``src`` is a host page
+        buffer that already holds ``pages`` (``DevicePagePool.scatter``).
+        Returns the bytes landed (== len(pages) * page_bytes)."""
+        if table is None:
+            return pool.scatter(pages, src, device=self.device)
+        return pool.land(pages, table, src, first=first,
+                         device=self.device)
 
     def free_pages(self, pool, pages: Sequence[int]) -> None:
         """Return pages to the pool's free list (pin/zombie protection
         applies unchanged).  No device write: a freed page is
-        unreachable until realloc re-stages and re-scatters it."""
+        unreachable until realloc lands it again."""
         pool.free(pages)
 
 
@@ -519,6 +527,7 @@ class BatchedLiveExecutor(LiveExecutor):
                          device_pool=device_pool, device=device)
         self.batching = batching or BatchingConfig()
         self._warmed: set = set()
+        self._pool_warmed: set = set()      # (prefill grid, pool pages)
 
     # --- per-request paths on the bucket grid -------------------------------
 
@@ -658,7 +667,9 @@ class BatchedLiveExecutor(LiveExecutor):
         With ``page_tokens`` set, also pre-compiles the
         ``rank_with_pages`` entries keyed (page-count bucket, batch) —
         ``pool_pages`` must match the serving store's pool size (the
-        pool buffer shape is part of the jit key)."""
+        pool buffer shape is part of the jit key).  On a device pool it
+        also compiles the pool's landing and gather programs for the
+        64-token prefill grid of every length (``_warm_pool``)."""
         from collections import Counter
         jax, jnp = self._jax, self._jax.numpy
         cfg = self.model.cfg
@@ -695,4 +706,41 @@ class BatchedLiveExecutor(LiveExecutor):
                         self.params, buf, tables, incr, items))
                 self._warmed.add(key)
                 done.append(key)
+        if self.device_pool and pool_pages:
+            self._warm_pool(prefix_lens, pool_pages)
         return done
+
+    def _warm_pool(self, prefix_lens: Sequence[int], pool_pages: int
+                   ) -> None:
+        """Compile ``DevicePagePool``'s landing (keyed by the psi's
+        length) and gather (keyed by its page count) for the prefill
+        grid of each of ``prefix_lens``: the lengths a prefill, a DRAM
+        reload or a handoff lands and a spill reads back.  Compiled
+        ahead of time from shapes, on 8 threads; the
+        placement matches the served arrays' (committed to ``device``
+        where the executor has one), so the served calls hit."""
+        from concurrent.futures import ThreadPoolExecutor
+        from jax.sharding import SingleDeviceSharding
+        from .paging import _gather_jit, _land_jit
+        jax, cfg = self._jax, self.model.cfg
+        dt = jax.numpy.dtype(cfg.dtype)
+        pt, slabs = self.page_tokens, 2 * cfg.n_layers
+        where = (None if self.device is None
+                 else SingleDeviceSharding(self.device))
+        spec = lambda shape, dtype=dt, sharding=where: jax.ShapeDtypeStruct(
+            shape, dtype, sharding=sharding)
+        buf = spec((pool_pages + 1, pt, cfg.n_heads * cfg.head_dim))
+        grids = sorted(g for g in {prefill_grid(int(n)) for n in prefix_lens}
+                       if (g, pool_pages) not in self._pool_warmed)
+
+        def compile_both(g):
+            n = ceil_div(g, pt)
+            kv = spec((cfg.n_layers, 1, g, cfg.n_heads, cfg.head_dim))
+            _land_jit().lower(buf, spec((slabs * n,), np.int32, None),
+                              kv, kv).compile()
+            _gather_jit().lower(buf, spec((slabs, n), np.int32,
+                                          None)).compile()
+
+        with ThreadPoolExecutor(8) as pool:
+            list(pool.map(compile_both, grids))
+        self._pool_warmed.update((g, pool_pages) for g in grids)

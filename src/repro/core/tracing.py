@@ -24,11 +24,15 @@ of one request):
     exec.prepare       host input building inside a launch
     exec.put           host-to-device puts inside a launch (bytes)
     exec.wait          dispatch through block_until_ready
-    window.stage       psi sliced into the host page mirror (d2h_bytes,
-                       mirror_bytes)
-    window.scatter     freshly staged pages landed on the device (pages,
+    window.stage       psi readied for the window: on a host pool, sliced
+                       into its page buffer (d2h_bytes, mirror_bytes);
+                       on a device pool, the landing's index built and
+                       a host value put on the device (pages, put_bytes)
+    window.scatter     the landing dispatched on the device (pages,
                        bytes)
-    window.materialize a dense host copy gathered out of the pool (bytes)
+    window.materialize a dense host copy gathered out of the pool
+                       (bytes); a device pool gathers on the device and
+                       pulls once
     dram.spill         psi copied into the DRAM expander (uid, bytes)
     relay.sink         scores handed to the request's sink (req, uid)
 

@@ -1,16 +1,19 @@
 """Device-resident page pool — parity, ledger, and launch contracts.
 
-The ``DevicePagePool`` keeps the page-pool data plane on device and
-mutates it in place (donated scatter at insert/resume); correctness is
-defined relative to the host-buffer pool:
+The ``DevicePagePool`` keeps the page-pool data plane on device, as
+the only copy, and mutates it in place (donated landing at
+insert/resume); correctness is defined relative to a host-buffer pool
+driven through the same operations:
 
   * after ANY interleaving of insert / partial tail-evict /
-    resume-reload / extract-handoff, the device mirror is byte-equal to
-    the host buffer on every page a launch could reference (live or
-    pinned), page accounting is conserved, and the null page stays
-    zero — so gathered K/V, and therefore scores, bit-match the
-    host-buffer path (hypothesis-driven via ``tests/_hyp``, plus a
-    deterministic interleaving that always runs);
+    resume-reload / extract-handoff, the device buffer is byte-equal to
+    the host twin's buffer on every page a launch could reference (live
+    or pinned), ``materialize()`` gives the twin's dense copy bit for
+    bit, page accounting is conserved, no host page mirror exists, and
+    the null page stays zero — so gathered K/V, and therefore scores,
+    bit-match the host-buffer path (hypothesis-driven via
+    ``tests/_hyp``, plus a deterministic interleaving that always
+    runs);
   * end to end through ``RelayRuntime``, the device-pool deployment
     scores bit-identically to the host-buffer deployment while its
     ``h2d`` ledger reads ``launch_reships == 0`` and
@@ -32,6 +35,7 @@ from repro.core import (BatchingConfig, ClusterConfig, DevicePagePool,
                         GRCostModel, HitKind, PageLayout, TriggerConfig,
                         UserMeta, get_executor, relay_config)
 from repro.core.cache import PagedHBMStore, kv_nbytes
+from repro.core.paging import PagedPsi
 from repro.core.runtime import RelayRuntime
 from repro.models import get_config
 
@@ -67,33 +71,46 @@ def _resident_pages(store: PagedHBMStore, entry) -> np.ndarray:
     return entry.page_table[:, :pps].reshape(-1)
 
 
-def _check_mirror_and_conservation(store: PagedHBMStore, pinned) -> None:
+def _check_mirror_and_conservation(store: PagedHBMStore, pinned,
+                                   twin: PagedHBMStore, twin_pinned) -> None:
+    """The device pool against its host-pool twin, driven through the
+    same ops: conserved pages, no host page mirror, and the device
+    buffer byte-equal to the twin's host buffer on every page a launch
+    could reference, and on every dense copy ``materialize`` gives."""
     pool = store.pool
     assert pool.stats["pages_allocated"] == \
         pool.pages_live + pool.stats["pages_freed"]
     assert pool.h2d["launch_reships"] == 0
     assert pool.h2d["bytes_scattered"] == \
         pool.h2d["pages_scattered"] * pool.page_bytes
-    if not isinstance(pool, DevicePagePool) or pool.device_buffer is None:
+    assert store.buffer is None, "a device pool keeps no host mirror"
+    assert pool.h2d["mirror_bytes"] == 0
+    if pool.device_buffer is None:
         return
     dev = np.asarray(pool.device_buffer)
     assert not dev[pool.n_pages].any(), "null page must stay zero"
-    for e in store.entries.values():
+    for uid, e in store.entries.items():
         if e.page_table is None:
             continue
-        pages = _resident_pages(store, e)
-        assert dev[pages].tobytes() == store.buffer[pages].tobytes()
-    for psi in pinned:
+        te = twin.entries[uid]
+        assert dev[_resident_pages(store, e)].tobytes() == \
+            twin.buffer[_resident_pages(twin, te)].tobytes()
+        if isinstance(e.value, PagedPsi) \
+                and e.tokens_resident >= e.prefix_len:
+            for a, b in zip(e.value.materialize(), te.value.materialize()):
+                assert a.shape == b.shape and a.dtype == b.dtype
+                assert a.tobytes() == b.tobytes()
+    for psi, tpsi in zip(pinned, twin_pinned):
         # an in-flight launch's pinned snapshot stays readable and
         # byte-stable even after the window freed/recycled around it
         assert dev[psi.table.reshape(-1)].tobytes() == \
-            store.buffer[psi.table.reshape(-1)].tobytes()
+            twin.buffer[tpsi.table.reshape(-1)].tobytes()
 
 
 def _drive_pair(ops):
     """Apply one op sequence to a host-buffer store and a device-pool
-    store; after every step the device mirror must bit-match the host
-    data plane and both stores must agree entry-for-entry."""
+    store; after every step both stores must agree entry-for-entry and
+    the device pool must bit-match the host store's data plane."""
     host, dev = _store(False), _store(True)
     pinned = {id(host): [], id(dev): []}
     now = 0.0
@@ -120,17 +137,14 @@ def _drive_pair(ops):
                     pinned[id(s)].append(s.acquire_value(e))
             elif op == "release" and pinned[id(s)]:
                 s.release_value(pinned[id(s)].pop(0))
-        _check_mirror_and_conservation(dev, pinned[id(dev)])
         # identical window decisions on both flavours...
         assert sorted(host.entries) == sorted(dev.entries)
         assert host.stats == dev.stats
         for uid_, he in host.entries.items():
-            de = dev.entries[uid_]
-            assert he.tokens_resident == de.tokens_resident
-            # ...and identical page data (the score-determining input)
-            if he.page_table is not None and host.buffer is not None:
-                hp, dp = _resident_pages(host, he), _resident_pages(dev, de)
-                assert host.buffer[hp].tobytes() == dev.buffer[dp].tobytes()
+            assert he.tokens_resident == dev.entries[uid_].tokens_resident
+        # ...and identical page data (the score-determining input)
+        _check_mirror_and_conservation(dev, pinned[id(dev)],
+                                       host, pinned[id(host)])
     return host, dev
 
 
@@ -190,7 +204,7 @@ def test_device_pool_interleaving_parity_property(ops):
 def test_device_pool_free_list_reuse_never_aliases(uids):
     """Churn a window smaller than the working set so freed pages are
     constantly reallocated to OTHER users: if a recycled page ever
-    served stale bytes, the mirror/materialize comparison would catch
+    served stale bytes, the page/materialize comparison would catch
     the alias on the very step it appears."""
     host, dev = _drive_pair([("insert", u) for u in uids])
     assert dev.pool.stats["pages_freed"] > 0, "no reuse pressure"
@@ -213,7 +227,6 @@ def test_page_launch_args_refuses_truncation():
     than the launch bucket must raise, not drop cached pages."""
     import jax.numpy as jnp
     from repro.core.executors import _page_launch_args
-    from repro.core.paging import PagedPsi
     buf = np.zeros((9, PT, H, D), np.float32)
     table = np.arange(8, dtype=np.int32).reshape(4, 2)  # 2 pages/slab
     psi = PagedPsi(table, 2 * PT, LAYOUT, buf)

@@ -23,7 +23,7 @@ from repro.core import (OFF, BatchingConfig, ClusterConfig, GRCostModel,
                         TriggerConfig, UserMeta, get_executor, relay_config)
 from repro.core.cache import PagedHBMStore, kv_nbytes
 from repro.core.expander import DRAMExpander, ExpanderConfig
-from repro.core.paging import _scatter_jit
+from repro.core.paging import _gather_jit, _land_jit, _scatter_jit
 from repro.models import get_config
 
 PT = 32
@@ -177,6 +177,12 @@ def test_self_time_is_duration_less_children(traced):
         assert tracer.self_seconds(i) >= -1e-9
 
 
+def _ones_psi(cfg, tokens, fill=1.0):
+    shape = (cfg.n_layers, 1, tokens, cfg.n_heads, cfg.head_dim)
+    return (jax.numpy.full(shape, fill, jax.numpy.float32),
+            jax.numpy.full(shape, 2 * fill, jax.numpy.float32))
+
+
 def test_h2d_ledger_counts_insert_spill_and_reload(live):
     cfg = live[0]
     layout = PageLayout.from_model_config(cfg, PT)
@@ -184,33 +190,134 @@ def test_h2d_ledger_counts_insert_spill_and_reload(live):
                           device_pool=True)
     dram = DRAMExpander(ExpanderConfig(dram_budget_bytes=1e9))
     L = 100                                    # tokens, page-unaligned
-    shape = (cfg.n_layers, 1, L, cfg.n_heads, cfg.head_dim)
-    psi = (jax.numpy.ones(shape, jax.numpy.float32),
-           jax.numpy.full(shape, 2.0, jax.numpy.float32))
-    value_bytes = 2 * int(np.prod(shape)) * 4
+    psi = _ones_psi(cfg, L)
     entry_bytes = layout.entry_bytes(L)
     h2d = store.pool.h2d
 
+    # psi already on the device lands from there: no pull, no mirror
     store.insert(7, psi, kv_nbytes(psi), 0.0, prefix_len=L)
-    assert h2d["d2h_bytes"] == value_bytes
-    assert h2d["mirror_bytes"] == entry_bytes
+    assert h2d["d2h_bytes"] == 0
+    assert h2d["mirror_bytes"] == 0
     assert h2d["bytes_scattered"] == entry_bytes
+    assert h2d["device_sourced_bytes"] == h2d["bytes_scattered"]
     assert h2d["materialized_bytes"] == 0
+    assert store.buffer is None
 
+    # the spill's dense copy is one pull off the device
     entry = store.consume(7)
     assert dram.spill(entry)
     assert h2d["materialized_bytes"] == entry_bytes
+    assert h2d["d2h_bytes"] == entry_bytes
+    k, v = dram.entries[7].value
+    assert isinstance(k, np.ndarray) and k.nbytes + v.nbytes == entry_bytes
+    assert (k[:, :, :L] == 1.0).all() and not k[:, :, L:].any()
+    assert (v[:, :, :L] == 2.0).all() and not v[:, :, L:].any()
     entry.dram_backed = True                 # as the runtime marks it
     store.pop(7)                             # so leaving copies nothing
     assert h2d["materialized_bytes"] == entry_bytes
 
     dram.flight.begin(7)
-    dram.complete_reload(7, store, 1.0)      # a host copy: no D2H pull
+    dram.complete_reload(7, store, 1.0)      # a host copy: one put
     assert store.resident(7) is not None
-    assert h2d["d2h_bytes"] == value_bytes
-    assert h2d["mirror_bytes"] == 2 * entry_bytes
+    assert h2d["d2h_bytes"] == entry_bytes
+    assert h2d["mirror_bytes"] == 0
     assert h2d["bytes_scattered"] == 2 * entry_bytes
+    assert h2d["device_sourced_bytes"] == entry_bytes
     assert h2d["materialized_bytes"] == entry_bytes
+    back = store.entries[7].value.materialize()
+    assert all(a.tobytes() == b.tobytes() for a, b in zip(back, (k, v)))
+    assert store.buffer is None
+
+
+def test_h2d_ledger_of_a_host_pool_counts_its_page_buffer(live):
+    """A host-buffer pool stages psi through its page buffer: a device
+    value is pulled (``d2h_bytes``) and sliced in (``mirror_bytes``),
+    and nothing lands on a device."""
+    cfg = live[0]
+    layout = PageLayout.from_model_config(cfg, PT)
+    store = PagedHBMStore(16 * layout.entry_bytes(256), layout)
+    L = 100
+    psi = _ones_psi(cfg, L)
+    store.insert(7, psi, kv_nbytes(psi), 0.0, prefix_len=L)
+    h2d = store.pool.h2d
+    assert h2d["d2h_bytes"] == kv_nbytes(psi)
+    assert h2d["mirror_bytes"] == layout.entry_bytes(L)
+    assert h2d["bytes_scattered"] == h2d["device_sourced_bytes"] == 0
+    assert store.buffer is not None
+
+
+def test_resumed_reload_keeps_the_resident_head_pages(live):
+    """A resumed reload lands only the missing tail: the head pages'
+    indices point past the pool's end and the update drops them, so
+    their device bytes stay as they were even when the reloaded value
+    differs there — and the null page stays zero."""
+    cfg = live[0]
+    layout = PageLayout.from_model_config(cfg, PT)
+    L = 4 * PT                                 # 4 pages per slab
+    store = PagedHBMStore(layout.entry_bytes(L), layout, device_pool=True)
+    psi = _ones_psi(cfg, L, fill=1.0)
+    store.insert(1, psi, kv_nbytes(psi), 0.0, prefix_len=L)
+    store.consume(1)
+    store.entries[1].dram_backed = True
+    other = _ones_psi(cfg, PT, fill=5.0)       # pressure: tail-evicts 1
+    store.insert(2, other, kv_nbytes(other), 1.0, prefix_len=PT)
+    e = store.entries[1]
+    assert store.stats["partial_evictions"] == 1
+    assert 0 < e.tokens_resident < L
+    head = e.page_table[:, :layout.pages_per_slab(e.tokens_resident)]
+    before = np.asarray(store.pool.device_buffer)[head.reshape(-1)].copy()
+    store.pop(2)
+    scattered = store.pool.h2d["pages_scattered"]
+    reload = tuple(np.asarray(a) * 3.0 for a in psi)   # differs everywhere
+    store.insert(1, reload, kv_nbytes(reload), 2.0, prefix_len=L)
+    assert store.stats["resumed_reloads"] == 1
+    pool = store.pool
+    dev = np.asarray(pool.device_buffer)
+    assert dev[head.reshape(-1)].tobytes() == before.tobytes()
+    tail = e.page_table[:, head.shape[1]:].reshape(-1)
+    assert pool.h2d["pages_scattered"] - scattered == tail.size
+    assert set(np.unique(dev[tail])) <= {3.0, 6.0}
+    assert not dev[pool.n_pages].any(), "null page must stay zero"
+
+
+def test_warmup_leaves_served_landing_and_spill_without_compiles(live):
+    """After ``warmup`` with the pool's size, inserting a prefill's psi
+    of every warmed length, spilling it and reloading the host copy
+    compile nothing (JAX's backend-compile event, as the benchmark's
+    ``CompileLedger`` counts them)."""
+    cfg, _, _, behaviour = live
+    ex = _executor(live)
+    layout = ex.page_layout
+    lens = [40, 100, 130]                      # grids 64, 128, 192
+    pool_pages = 3 * layout.entry_pages(192)
+    ex.warmup(lens, pool_pages=pool_pages, incr_len=8, n_items=16)
+    store = PagedHBMStore(pool_pages * layout.page_bytes, layout,
+                          device_pool=True)
+    store.device_hooks = ex
+    psis = {n: ex.pre_infer(UserMeta(user_id=400 + n, prefix_len=n,
+                                     incr_len=8, n_items=16))[0]
+            for n in lens}
+    store.pool.ensure_device((PT, cfg.n_heads * cfg.head_dim),
+                             np.float32, ex.device)
+    compiled = []
+
+    def on_event(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(kw.get("fun_name"))
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        for i, (n, psi) in enumerate(psis.items()):
+            store.insert(n, psi, kv_nbytes(psi), float(i), prefix_len=n)
+            dense = store.consume(n).value.materialize()
+            store.pop(n)
+            store.insert(n, dense, kv_nbytes(dense), float(i), prefix_len=n)
+            store.pop(n)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(
+            on_event)
+    assert store.pool.h2d["scatters"] == 2 * len(lens)
+    assert compiled == []
 
 
 def test_rank_counters_of_a_padded_group(live):
@@ -242,6 +349,14 @@ def test_pool_scatter_program_is_named():
     text = _scatter_jit().lower(buf, np.zeros(1, np.int32),
                                 np.zeros((1, 2, 3), np.float32)).as_text()
     assert "jit_pool_scatter" in text
+
+
+def test_pool_landing_and_gather_programs_are_named():
+    buf = jax.numpy.zeros((5, 2, 6))
+    kv = np.zeros((1, 1, 3, 2, 3), np.float32)
+    land = _land_jit().lower(buf, np.zeros(4, np.int32), kv, kv).as_text()
+    gather = _gather_jit().lower(buf, np.zeros((2, 2), np.int32)).as_text()
+    assert "jit_pool_scatter" in land and "jit_pool_gather" in gather
 
 
 def test_stats_carry_no_slo_tracker(live):
