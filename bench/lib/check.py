@@ -3,25 +3,25 @@ window's completed requests against the plain reference.
 
 The sample is drawn from the seed, and always holds the longest
 history served from cached psi and the longest served by a full rank.
-For each request the reference is fed the sequence that path served
-(``served_prefix``), and the gap is
+For each request the model's reference (``bench/models/<arch>.py``) is
+fed the sequence that path served (its ``served_prefix``), and the gap
+is
 
     max |served - reference| / max |reference|
 
 over the request's candidate scores.  The number compared is the
-widest gap of the sample, against the reference in float32 at the
-highest precision, which is what the configurations state.  The
-control puts the reference computed at the next precision down
-(``high``: three bfloat16 passes) in the program's place.
+widest gap of the sample, against the reference at the precision the
+configuration states (the module's first mode).  A control puts the
+reference at one of the module's lower precisions in the program's
+place.
 """
 
 from __future__ import annotations
 
+from types import ModuleType
 from typing import Dict, List, Sequence, Set
 
 import numpy as np
-
-from . import reference
 
 
 def gap(served, want) -> float:
@@ -59,48 +59,31 @@ def _cached(req) -> bool:
     return req.result.hit.value != "miss"
 
 
-def served_prefix(store, uid: int, cached: bool):
-    """(tokens, n_fed) of the prefix a path served: a cached rank
-    attends psi of the history tiled to the 64-token prefill grid,
-    zero-padded to the rank bucket; a full rank infers the history
-    tiled to the bucket."""
-    from repro.serving.batching import bucket_of, prefill_grid
-    plen = store.prefix_len(uid)
-    hist = store.long_term(uid)
-    bucket = bucket_of(plen)
-    if not cached:
-        return np.resize(hist, bucket).astype(np.int32), bucket
-    grid = prefill_grid(plen)
-    tokens = np.zeros(bucket, np.int32)
-    tokens[:grid] = np.resize(hist, grid)
-    return tokens, grid
-
-
-def collect(reqs: Sequence, store) -> List[Dict]:
+def collect(reqs: Sequence, store, arch: ModuleType) -> List[Dict]:
     """Everything the comparison needs from the program, on the host,
     so that the program's state can be freed before it runs."""
     out = []
     for r in reqs:
         cached = _cached(r)
-        tokens, n_fed = served_prefix(store, r.uid, cached)
+        tokens, n_fed = arch.served_prefix(store, r.uid, cached)
         out.append({"uid": r.uid, "cached": cached,
                     "prefix_len": r.prefix_len, "tokens": tokens,
                     "n_fed": n_fed, "incr": store.short_term(r.uid),
                     "items": store.candidates(r.uid),
-                    "served": np.asarray(r.result.scores, np.float32)})
+                    "served": np.asarray(r.result.scores)})
     return out
 
 
-def compare(dims: dict, weights, sample: Sequence[Dict],
+def compare(arch: ModuleType, dims: dict, weights, sample: Sequence[Dict],
             served_mode: str = "") -> Dict[str, float]:
-    """Widest gap of the sample, by hit class.  ``served_mode`` puts
-    the reference at that precision in the program's place (the
-    control)."""
+    """Widest gap of the sample, by hit class, against ``arch``'s
+    reference at its first mode.  ``served_mode`` puts the reference at
+    that precision in the program's place (a control)."""
     gaps = {"cached": [], "full": []}
     for s in sample:
-        want = reference.rank_scores(dims, weights, s["tokens"], s["n_fed"],
-                                     s["incr"], s["items"], "f32")
-        got = s["served"] if not served_mode else reference.rank_scores(
+        want = arch.rank_scores(dims, weights, s["tokens"], s["n_fed"],
+                                s["incr"], s["items"], arch.MODES[0])
+        got = s["served"] if not served_mode else arch.rank_scores(
             dims, weights, s["tokens"], s["n_fed"], s["incr"], s["items"],
             served_mode)
         gaps["cached" if s["cached"] else "full"].append(

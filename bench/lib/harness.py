@@ -16,11 +16,12 @@ from __future__ import annotations
 
 import dataclasses
 from concurrent.futures import ThreadPoolExecutor
+from types import ModuleType
 from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
-from . import reference, spans
+from . import spans, spec
 from .clock import PacingClock, WindowClosed
 
 # a device without memory statistics (the CPU backend) gets this window
@@ -45,7 +46,8 @@ class Deployment:
     svc: Any
     executors: List[Any]
     store: Any
-    model_cfg: dict
+    arch: ModuleType                 # bench/models/<arch>.py
+    model_cfg: dict                  # arch.dims of the program's config
     weights: Any
     clock: PacingClock
     log: spans.LaunchLog
@@ -55,13 +57,17 @@ class Deployment:
     n_items: int
     rank_budget_ms: float
     slack_s: float                   # retrieval + pre-processing
+    tracer: Any = None               # the program's Tracer, on; traced runs
 
 
-def model_dims(cfg) -> dict:
-    """The numbers of a program ``ModelConfig`` the benchmark uses."""
-    return {k: getattr(cfg, k) for k in
-            ("n_layers", "d_model", "n_heads", "head_dim", "n_tasks",
-             "rope_theta", "vocab", "dtype")}
+def model_module(config: dict) -> ModuleType:
+    """The benchmark's module of ``config["model"]["arch"]``:
+    ``bench/models/<arch>.py`` with ``-`` as ``_``.  An arch without
+    one raises ``FileNotFoundError`` naming the path."""
+    arch = config["model"]["arch"]
+    return spec.load_file(
+        spec.BENCH / "models" / f"{arch.replace('-', '_')}.py",
+        "bench_model_", f"benchmark module for model arch {arch!r}")
 
 
 def model_config(config: dict, rehearse: bool):
@@ -85,10 +91,15 @@ def seed_key(seed: int):
 
 def build(config: dict, traffic: dict, seed: int, rehearse: bool,
           annotate: bool) -> Deployment:
+    """The cell's deployment.  ``annotate`` (a traced run) wraps the
+    launches in profiler spans and gives the service the program's own
+    ``Tracer``, on."""
+    arch = model_module(config)
     import jax
     from repro.core import (BatchingConfig, ClusterConfig, GRCostModel,
                             RelayGRService, TriggerConfig, get_executor,
                             relay_config)
+    from repro.core.tracing import Tracer
     from repro.data.synthetic import UserBehaviorStore, WorkloadConfig
     from repro.models import build_model, get_config
 
@@ -96,9 +107,9 @@ def build(config: dict, traffic: dict, seed: int, rehearse: bool,
     cap = config["rehearse"]["history_cap"] if rehearse \
         else config["history_cap"]
     cfg = model_config(config, rehearse)
-    dims = model_dims(cfg)
+    dims = arch.dims(cfg)
     model = build_model(cfg)
-    weights = reference.make_weights(dims, seed_key(seed))
+    weights = arch.make_weights(dims, seed_key(seed))
     want = jax.tree.map(lambda a: (a.shape, a.dtype),
                         jax.eval_shape(model.init, jax.random.PRNGKey(0)))
     got = jax.tree.map(lambda a: (a.shape, a.dtype), weights)
@@ -148,13 +159,14 @@ def build(config: dict, traffic: dict, seed: int, rehearse: bool,
         return executors[order.setdefault(name, len(order)) % len(executors)]
 
     clock = PacingClock(annotate)
+    tracer = Tracer(on=True) if annotate else None
     svc = RelayGRService(relay_cfg, cost, executor_factory=factory,
-                         clock=clock)
+                         clock=clock, tracer=tracer)
     pp = svc.runtime.cfg.pipeline
-    return Deployment(svc, executors, store, dims, weights, clock,
+    return Deployment(svc, executors, store, arch, dims, weights, clock,
                       log, devices, wbytes, traffic["incr_len"],
                       traffic["n_items"], budget,
-                      (pp.retrieval_ms + pp.preprocess_ms) / 1e3)
+                      (pp.retrieval_ms + pp.preprocess_ms) / 1e3, tracer)
 
 
 def window_bytes(devices, instances: int, share: float) -> int:
@@ -174,18 +186,16 @@ def warm(dep: Deployment, prefix_lens: List[int], threads: int = 4
          ) -> Dict[str, int]:
     """Compile (or load from the persistent cache) every program the
     traffic can reach: the rank programs of each bucket and batch size
-    (the executor's own ``warmup``), the prefill of each 64-token grid
-    length and batch size, and the pool scatter of each page-count
-    grid."""
-    from repro.core.paging import DevicePagePool
+    and the pool's landing and gather of each prefill grid length (the
+    executor's own ``warmup``), and the prefill of each 64-token grid
+    length and batch size."""
     from repro.core.types import UserMeta
     from repro.serving.batching import bucket_of, prefill_grid
     ex0 = dep.executors[0]
     max_batch = ex0.batching.max_batch
     sizes = sorted({batch_grid(b, max_batch)
                     for b in range(1, max_batch + 1)})
-    layout = ex0.page_layout
-    pool_pages = dep.window_bytes // layout.page_bytes
+    pool_pages = dep.window_bytes // ex0.page_layout.page_bytes
     counts = {"buckets": len({bucket_of(n) for n in prefix_lens})}
     for ex in dep.executors:
         ex.warmup(prefix_lens, batch_sizes=range(1, max_batch + 1),
@@ -203,20 +213,6 @@ def warm(dep: Deployment, prefix_lens: List[int], threads: int = 4
     jobs = [(ex, g, b) for ex in dep.executors for g in grids for b in sizes]
     with ThreadPoolExecutor(threads) as pool:
         list(pool.map(prefill, jobs))
-    # the donated pool scatter: one program per power-of-two page count
-    most = layout.entry_pages(max(grids))
-    for ex in dep.executors:
-        scratch = DevicePagePool(pool_pages, layout.page_bytes)
-        host = np.zeros((pool_pages + 1, layout.page_tokens, dep.model_cfg[
-            "n_heads"], dep.model_cfg["head_dim"]), np.float32)
-        g = 1
-        while True:
-            ex.insert_pages(scratch, list(range(min(g, pool_pages))), host)
-            if g >= most:
-                break
-            g *= 2
-        del scratch, host
-    counts["scatter_grids"] = g.bit_length()
     return counts
 
 

@@ -14,14 +14,19 @@ readings:
     psi_host_ms        time in window.stage, window.scatter,
                        window.materialize and dram.spill (their union)
                        per psi staged into the window
-    psi_host_mb        the pool's d2h + mirror + materialized + scattered
-                       bytes per psi staged
+    psi_host_mb        megabytes of psi that crossed between host and
+                       device or were copied on the host, per psi staged
+                       (``psi_host_bytes``)
     rank_pad_share     100 x (1 - real / launched prefix tokens) of the
                        rank launches
     idle_unattributed_share
                        share of the window in which the device is idle,
                        the host is not waiting for an arrival and is in
                        no program span
+
+``ledgers`` sums a deployment's executor counters and page-pool ``h2d``
+ledgers, each pool's with its ``psi_host_bytes``; ``less`` of two such
+sums is the window's share.
 
 ``idle_gaps`` names each idle gap by the program span whose own
 (innermost) time covers most of it, or ``wait_arrival``, and falls back
@@ -47,8 +52,45 @@ PROGRAM_SPANS = ("relay.event", "relay.sink", "exec.rank", "exec.prefill",
                  "window.scatter", "window.materialize", "dram.spill")
 PSI_HOST_SPANS = ("window.stage", "window.scatter", "window.materialize",
                   "dram.spill")
-PSI_HOST_BYTES = ("d2h_bytes", "mirror_bytes", "materialized_bytes",
-                  "bytes_scattered")
+
+
+def _sum(ledgers) -> Dict[str, int]:
+    out: Dict[str, int] = defaultdict(int)
+    for ledger in ledgers:
+        for k, v in ledger.items():
+            out[k] += v
+    return dict(out)
+
+
+def psi_host_bytes(h2d: Dict[str, int], device_pool: bool) -> int:
+    """Bytes of psi that one page pool's ledger says crossed between
+    host and device or were copied on the host: pulls to the host
+    (``d2h_bytes``), host page-buffer writes (``mirror_bytes``) and
+    landings put from the host (scattered but not device-sourced).  A
+    host pool's dense copies (``materialized_bytes``) are host copies
+    too; a device pool's are its gathers' pulls, already in
+    ``d2h_bytes``."""
+    out = (h2d.get("d2h_bytes", 0) + h2d.get("mirror_bytes", 0)
+           + h2d.get("bytes_scattered", 0)
+           - h2d.get("device_sourced_bytes", 0))
+    if not device_pool:
+        out += h2d.get("materialized_bytes", 0)
+    return out
+
+
+def ledgers(dep):
+    """(executor counters, pool h2d ledgers) of the deployment, summed;
+    the pools' sum carries ``psi_host_bytes``."""
+    from repro.core.paging import DevicePagePool
+    pools = [i.hbm.pool for i in dep.svc.instances.values()
+             if getattr(i.hbm, "pool", None) is not None]
+    return (_sum(ex.counters for ex in dep.executors),
+            _sum({**p.h2d, "psi_host_bytes": psi_host_bytes(
+                p.h2d, isinstance(p, DevicePagePool))} for p in pools))
+
+
+def less(after: Dict[str, int], before: Dict[str, int]) -> Dict[str, int]:
+    return {k: v - before.get(k, 0) for k, v in after.items()}
 
 
 def load(profile_dir: str) -> List[tr.Span]:
@@ -70,37 +112,6 @@ def load(profile_dir: str) -> List[tr.Span]:
                     out.append(tr.Span(ev.name, s, s + ev.duration_ns * 1e-9,
                                        tr._stats(ev)))
     return out
-
-
-def program_device_s(profile_dir: str, lo: float, hi: float
-                     ) -> Dict[str, float]:
-    """Device seconds inside [lo, hi) per jitted program: the device
-    planes' ``XLA Modules`` events (``jit_<name>(<id>)``), or on the CPU
-    backend the host operations' ``hlo_module``."""
-    from jax.profiler import ProfileData
-    paths = sorted(glob.glob(os.path.join(profile_dir, "**", "*.xplane.pb"),
-                             recursive=True), key=os.path.getmtime)
-    device: Dict[str, float] = defaultdict(float)
-    host: Dict[str, float] = defaultdict(float)
-    for plane in ProfileData.from_file(paths[-1]).planes:
-        on_device = plane.name.startswith("/device:") \
-            and not plane.name.startswith("/device:CPU")
-        for line in plane.lines:
-            if on_device and line.name != "XLA Modules":
-                continue
-            for ev in line.events:
-                s = ev.start_ns * 1e-9
-                e = s + ev.duration_ns * 1e-9
-                if e <= lo or s >= hi:
-                    continue
-                if on_device:
-                    device[ev.name.split("(")[0]] += min(e, hi) - max(s, lo)
-                elif plane.name == "/host:CPU":
-                    module = tr._stats(ev).get("hlo_module")
-                    if module and ev.duration_ns > 0:
-                        host[str(module)] += min(e, hi) - max(s, lo)
-    out = device or host
-    return dict(sorted(out.items(), key=lambda kv: -kv[1]))
 
 
 # --- readings of the in-memory record ----------------------------------------
@@ -131,10 +142,11 @@ def psi_host_ms(spans: Sequence) -> Optional[float]:
 
 
 def psi_host_mb(h2d: Dict[str, int], staged: int) -> Optional[float]:
-    """``h2d``: the pools' ledger over the window (a difference)."""
-    if not staged:
+    """``h2d``: the pools' ledger over the window (a difference), with
+    ``psi_host_bytes``."""
+    if not staged or "psi_host_bytes" not in h2d:
         return None
-    return sum(h2d.get(k, 0) for k in PSI_HOST_BYTES) / staged / 1e6
+    return h2d["psi_host_bytes"] / staged / 1e6
 
 
 def rank_pad_share(counters: Dict[str, int]) -> Optional[float]:

@@ -3,8 +3,10 @@
 A cell (an entry of ``workloads``) names a configuration and a traffic
 mix.  The configuration's file is the one its entry in ``configs``
 gives; the traffic mix is ``bench/traffic/<traffic>.json``; a per-layer
-metric is read by ``bench/metrics/<name>.py``.  Adding any of them is
-adding files and entries: nothing here changes.
+metric is read by ``bench/metrics/<name>.py``; the model of a
+configuration's ``model.arch`` is ``bench/models/<arch>.py``
+(``bench.lib.harness.model_module``).  Adding any of them is adding
+files and entries: nothing here changes.
 """
 
 from __future__ import annotations
@@ -13,6 +15,7 @@ import importlib.util
 import json
 import re
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List
 
 BENCH = Path(__file__).resolve().parents[1]
@@ -47,16 +50,23 @@ def cell(name: str, root: Path = ROOT) -> dict:
             "per_layer": [m for m in bench["per_layer"] if reports(m)]}
 
 
-def metric_reader(name: str, root: Path = ROOT) -> Callable:
-    """``read(run)`` of ``bench/metrics/<name>.py``."""
-    path = Path(root) / "bench" / "metrics" / f"{name}.py"
-    mod_name = "bench_metric_" + re.sub(r"\W", "_", name)
+def load_file(path: Path, prefix: str, what: str) -> ModuleType:
+    """The Python file at ``path`` as a module of its own;
+    ``FileNotFoundError`` naming ``what`` and the path where there is
+    none."""
+    mod_name = prefix + re.sub(r"\W", "_", path.stem)
     spec = importlib.util.spec_from_file_location(mod_name, path)
     if spec is None or not path.exists():
-        raise FileNotFoundError(f"no reader for metric {name!r} at {path}")
+        raise FileNotFoundError(f"no {what} at {path}")
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str, root: Path = ROOT) -> Callable:
+    """``read(run)`` of ``bench/metrics/<name>.py``."""
+    return load_file(Path(root) / "bench" / "metrics" / f"{name}.py",
+                     "bench_metric_", f"reader for metric {name!r}").read
 
 
 def read_metrics(metrics: List[dict], run, root: Path = ROOT

@@ -2,12 +2,17 @@
 host spans that idle time falls in.
 
 ``load`` reads the ``.xplane.pb`` that ``jax.profiler`` writes into a
-``Trace``: per device, the intervals of the operations that ran on it,
-and the benchmark's host spans (``jax.profiler.TraceAnnotation``) with
-their arguments.  Host and device events share the profiler's clock.
-On a TPU the operations are the events of each device plane's ``XLA
-Ops`` line; on the CPU backend, which has no device plane, they are
-the host events that carry an ``hlo_op`` argument.
+``Trace``: per device, the intervals of the operations that ran on it
+and of each jitted program's executions, and the benchmark's host spans
+(``jax.profiler.TraceAnnotation``) with their arguments.  Host and
+device events share the profiler's clock, up to an offset that the
+first process on a fresh machine can show, so device time is given to
+a launch kind by the programs' names, not by the host spans it falls
+in.  On a TPU the operations are the events of each device plane's
+``XLA Ops`` line and the programs those of its ``XLA Modules`` line
+(``jit_<name>(<id>)``); on the CPU backend, which has no device plane,
+both are the host events that carry an ``hlo_op`` argument, the
+program named by their ``hlo_module``.
 
 Busy time is the union of operation intervals, so overlapping
 operations count once.  Everything else here is interval arithmetic on
@@ -40,6 +45,9 @@ class Span:
 class Trace:
     ops: Dict[str, List[Tuple[float, float, str]]]   # device -> (s, e, op)
     spans: List[Span]
+    # device -> (s, e, program), one entry per execution (per op on CPU)
+    programs: Dict[str, List[Tuple[float, float, str]]] = \
+        dataclasses.field(default_factory=dict)
 
     def window(self) -> Interval:
         w = [s for s in self.spans if s.name == "bench_window"]
@@ -76,18 +84,23 @@ def load(profile_dir: str) -> Trace:
         raise FileNotFoundError(f"no .xplane.pb under {profile_dir}")
     data = ProfileData.from_file(paths[-1])
     ops: Dict[str, list] = defaultdict(list)
+    programs: Dict[str, list] = defaultdict(list)
     spans: List[Span] = []
     host_ops: list = []
+    host_programs: list = []
     for plane in data.planes:
         device = plane.name.startswith("/device:") \
             and not plane.name.startswith("/device:CPU")
         for line in plane.lines:
-            if device and line.name != "XLA Ops":
+            if device and line.name not in ("XLA Ops", "XLA Modules"):
                 continue
             for ev in line.events:
                 s = ev.start_ns * 1e-9
                 e = s + ev.duration_ns * 1e-9
-                if device:
+                if device and line.name == "XLA Modules":
+                    programs[plane.name].append(
+                        (s, e, ev.name.split("(")[0]))
+                elif device:
                     ops[plane.name].append((s, e, _op_name(ev)))
                 elif plane.name == "/host:CPU":
                     if ev.name in SPANS:
@@ -96,9 +109,12 @@ def load(profile_dir: str) -> Trace:
                         st = _stats(ev)
                         if "hlo_op" in st:
                             host_ops.append((s, e, ev.name))
+                            host_programs.append(
+                                (s, e, str(st.get("hlo_module", ""))))
     if not ops and host_ops:
         ops["/host:CPU"] = host_ops
-    return Trace(dict(ops), spans)
+        programs["/host:CPU"] = host_programs
+    return Trace(dict(ops), spans, dict(programs))
 
 
 # --- interval arithmetic ------------------------------------------------------
@@ -171,7 +187,7 @@ class Reduced:
     window_s: float
     busy_s: float                     # mean over the devices used
     idle_host_s: float                # idle and the host not waiting
-    span_device_s: Dict[int, float]   # launch index -> device seconds
+    program_s: Dict[str, float]       # jitted program -> device seconds
     device_ops: List[list]            # [[op, seconds], ...] top 10
     idle_gaps: List[list]             # [[host span, seconds], ...] top 10
 
@@ -184,12 +200,13 @@ def reduce(trace: Trace, n_devices: int = 1,
             for d in names}
     wait = union([(s.start, s.end) for s in trace.spans
                   if s.name == "wait_arrival"])
-    launches = [s for s in trace.spans
-                if s.name in ("prefill", "rank", "scatter")
-                and "launch" in s.args]
-    all_busy = Cover(union([iv for d in names for iv in busy[d]]))
-    span_dev = {int(s.args["launch"]): all_busy(s.start, s.end)
-                for s in launches if s.end > lo and s.start < hi}
+    # each program's executions on the devices used, as one union
+    runs: Dict[str, list] = defaultdict(list)
+    for d in names:
+        for s, e, name in trace.programs.get(d, ()):
+            runs[name].append((s, e))
+    program_s = {name: length(clip(union(ivs), lo, hi))
+                 for name, ivs in runs.items()}
     busy_s = sum(covered(busy[d], lo, hi) for d in names) / len(names)
     # idle while the host was not waiting for an arrival: the idle time
     # less the waiting that fell on idle device time
@@ -210,7 +227,8 @@ def reduce(trace: Trace, n_devices: int = 1,
     top = sorted(per_op.items(), key=lambda kv: -kv[1])[:10]
     named.sort(key=lambda x: -x[0])
     return Reduced(window_s=hi - lo, busy_s=busy_s, idle_host_s=idle_host,
-                   span_device_s=span_dev,
+                   program_s={k: v for k, v in sorted(
+                       program_s.items(), key=lambda kv: -kv[1]) if v > 0},
                    device_ops=[[op, t] for op, t in top],
                    idle_gaps=[[_host_state(trace.spans, g), t]
                               for t, g in named[:10]])

@@ -1,5 +1,6 @@
 """Share of the chip's roofline that the rank launches reach: their
-least possible time (bench/lib/flops.py) over their device time."""
+least possible time (the work counts of bench/models/<arch>.py) over
+their device time."""
 
 from bench.lib.readings import roofline_pct
 

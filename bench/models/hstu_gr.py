@@ -1,7 +1,11 @@
-"""Plain HSTU reference and the benchmark's own weights.
+"""HSTU (``model.arch`` ``hstu-gr``) as the benchmark sees it: its
+sizes, the benchmark's own weights, the plain reference that decides
+``correct``, the sequence each served path computed, the pages its
+window holds, and the operations and bytes of each launch.
 
-Straight ``jax.numpy``, independent of the program's model code and
-kernels: the HSTU block of Zhai et al. (arXiv:2402.17152, Eq. 1-3)
+The reference is straight ``jax.numpy``, independent of the program's
+model code and kernels: the HSTU block of Zhai et al.
+(arXiv:2402.17152, Eq. 1-3)
 
     U, V, Q, K = split(SiLU(norm(x) W1))        rotary on Q and K
     A          = SiLU(Q K^T / sqrt(d_head)) / n   masked, no softmax
@@ -18,7 +22,8 @@ the rank then sees ``n_slots`` prefix positions (``n_fed`` computed
 ones, the rest zero keys, which contribute nothing), its own tokens at
 positions ``n_slots + i`` and the normaliser ``n_slots + n_incr +
 n_items``.  Attention runs in blocks of queries, so an 8K prefix fits.
-Three precisions (``mode``):
+Three precisions (``mode``; the first is the reference, the others
+controls):
 
 * ``"f32"``: float32 throughout, matmuls at the highest precision (the
   configurations state float32);
@@ -28,11 +33,23 @@ Three precisions (``mode``):
   so that it computes the same on any platform;
 * ``"bf16"``: the same equations with weights and activations in
   bfloat16.
+
+The work counts follow the same equations and count matrix-multiply
+operations only, over real rows and real tokens: no batch-padding row,
+no bucket or grid padding, and, where attention is causal or masked,
+only the query-key pairs that the mask keeps.  So a launch reads the
+same work whatever implements it, and a kernel that skips masked
+blocks raises its roofline share honestly.  Bytes are what the
+algorithm must move at the least: the weights it uses once per launch,
+the embedding rows of its tokens, psi where it is written (prefill) or
+read (a cached rank), the token ids in and the scores out.
+Intermediate activations and attention logits are not counted.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -44,6 +61,13 @@ Q_BLOCK = 512
 
 def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
+
+
+def dims(cfg) -> dict:
+    """The numbers of the program's ``ModelConfig`` the benchmark uses."""
+    return {k: getattr(cfg, k) for k in
+            ("n_layers", "d_model", "n_heads", "head_dim", "n_tasks",
+             "rope_theta", "vocab", "dtype")}
 
 
 def weight_shapes(cfg: dict) -> dict:
@@ -238,3 +262,105 @@ def rank_scores(cfg: dict, w, tokens, n_fed: int, incr, items,
                    jnp.asarray(n_fed, jnp.int32),
                    jnp.asarray(incr, jnp.int32),
                    jnp.asarray(items, jnp.int32), cfg_items, mode)
+
+
+def served_prefix(store, uid: int, cached: bool):
+    """(tokens, n_fed) of the prefix a path served: a cached rank
+    attends psi of the history tiled to the 64-token prefill grid,
+    zero-padded to the rank bucket; a full rank infers the history
+    tiled to the bucket."""
+    from repro.serving.batching import bucket_of, prefill_grid
+    plen = store.prefix_len(uid)
+    hist = store.long_term(uid)
+    bucket = bucket_of(plen)
+    if not cached:
+        return np.resize(hist, bucket).astype(np.int32), bucket
+    grid = prefill_grid(plen)
+    tokens = np.zeros(bucket, np.int32)
+    tokens[:grid] = np.resize(hist, grid)
+    return tokens, grid
+
+
+# --- operations and bytes of a launch ---------------------------------------
+
+
+def _dims(cfg: dict):
+    return (cfg["n_layers"], cfg["d_model"], cfg["n_heads"],
+            cfg["head_dim"], cfg.get("n_tasks", 1),
+            4 if cfg.get("dtype", "float32") == "float32" else 2)
+
+
+def layer_param_bytes(cfg: dict) -> int:
+    L, d, H, hd, _, b = _dims(cfg)
+    return L * (d * 4 * H * hd + H * hd * d + d + H * hd) * b
+
+
+def tower_param_bytes(cfg: dict) -> int:
+    _, d, _, _, T, b = _dims(cfg)
+    return (d * 4 * d + 4 * d * T) * b
+
+
+def _proj_per_token(cfg: dict) -> float:
+    L, d, H, hd, _, _ = _dims(cfg)
+    return L * (2.0 * d * 4 * H * hd + 2.0 * H * hd * d)
+
+
+def _attn_per_pair(cfg: dict) -> float:
+    L, _, H, hd, _, _ = _dims(cfg)
+    return L * 4.0 * H * hd          # Q.K and A.V, every head and layer
+
+
+def prefill_row_flops(cfg: dict, n: int) -> float:
+    """Causal prefill of ``n`` real tokens."""
+    return _proj_per_token(cfg) * n + _attn_per_pair(cfg) * n * (n + 1) / 2
+
+
+def rank_row_flops(cfg: dict, n_prefix: int, n_incr: int,
+                   n_items: int) -> float:
+    """Rank of ``n_items`` candidates after ``n_incr`` incremental
+    tokens over ``n_prefix`` cached tokens: incremental tokens attend
+    causally, each candidate sees the prefix, the incremental tokens
+    and itself."""
+    _, d, _, _, T, _ = _dims(cfg)
+    pairs = (n_incr * n_prefix + n_incr * (n_incr + 1) / 2
+             + n_items * (n_prefix + n_incr + 1))
+    tower = n_items * (2.0 * d * 4 * d + 2.0 * 4 * d * T)
+    return (_proj_per_token(cfg) * (n_incr + n_items)
+            + _attn_per_pair(cfg) * pairs + tower)
+
+
+def _psi_bytes(cfg: dict, n: int) -> int:
+    L, _, H, hd, _, b = _dims(cfg)
+    return L * 2 * n * H * hd * b
+
+
+def _embed_bytes(cfg: dict, n: int) -> int:
+    _, d, _, _, _, b = _dims(cfg)
+    return n * (d * b + 4)           # embedding row + int32 token id
+
+
+def prefill_work(cfg: dict, lens: Sequence[int]) -> Tuple[float, float]:
+    """(FLOPs, bytes) of one prefill launch over real rows ``lens``."""
+    flops = sum(prefill_row_flops(cfg, n) for n in lens)
+    nbytes = layer_param_bytes(cfg) + sum(
+        _embed_bytes(cfg, n) + _psi_bytes(cfg, n) for n in lens)
+    return flops, float(nbytes)
+
+
+def rank_work(cfg: dict, prefix_lens: Sequence[int], n_incr: int,
+              n_items: int, cached: bool) -> Tuple[float, float]:
+    """(FLOPs, bytes) of one rank launch over real rows: from cached
+    psi, or (``cached=False``) a full rank that first infers the
+    prefix itself."""
+    _, _, _, _, T, b = _dims(cfg)
+    flops = 0.0
+    nbytes = layer_param_bytes(cfg) + tower_param_bytes(cfg)
+    for p in prefix_lens:
+        flops += rank_row_flops(cfg, p, n_incr, n_items)
+        nbytes += _embed_bytes(cfg, n_incr + n_items) + n_items * T * b
+        if cached:
+            nbytes += _psi_bytes(cfg, p)
+        else:
+            flops += prefill_row_flops(cfg, p)
+            nbytes += _embed_bytes(cfg, p)
+    return flops, float(nbytes)

@@ -15,10 +15,15 @@ fell due (arrival + retrieval + pre-processing) to the wall time its
 scores reached the sink.
 
 ``--trace 0`` prints the cell's end-to-end metrics; ``--trace 1``
-profiles the window and prints its per-layer metrics.  After the
+profiles the window, with the program's own tracer on, prints its
+per-layer metrics, and logs the idle time split by the program span
+the host was in, the longest idle gaps outside ``wait_arrival`` and
+the device time of each jitted program (``phase: program``).  After the
 window, a sample of the served scores is compared with the plain
 reference (``bench/lib/check.py``); ``correct`` is that comparison.
-The last line of standard output is one JSON object.  Without a TPU
+The model's sizes, weights, reference and work counts come from
+``bench/models/<arch>.py`` (``harness.model_module``).  The last line
+of standard output is one JSON object.  Without a TPU
 (or with fewer chips than the cell asks for) the run exits non-zero
 and prints no result.
 
@@ -26,9 +31,9 @@ and prints no result.
 model's reduced smoke configuration and short histories; its result
 names the platform it ran on and is not a measurement.  ``--rate``
 overrides the mix's offered rate (the knee sweep); ``--control`` also
-reads the controls' gaps on the same sample: the reference at the
-next precision down (``high``) and in bfloat16, each in the program's
-place.
+reads the controls' gaps on the same sample: the reference at each of
+the model module's lower precisions (for HSTU ``high``, the next one
+down, and ``bf16``), each in the program's place.
 """
 
 from __future__ import annotations
@@ -51,6 +56,7 @@ ROOT = Path(__file__).resolve().parents[1]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 from bench.lib import check, harness, spec, stats, traffic  # noqa: E402
+from bench.lib import program_trace as pt  # noqa: E402
 
 
 def log(**kv) -> None:
@@ -94,6 +100,36 @@ def device_info(devices) -> dict:
         peak = max(peak, int(st.get("peak_bytes_in_use", 0)))
     return {"platform": d.platform, "kind": d.device_kind,
             "count": len(devices), "memory_peak_bytes": peak}
+
+
+def read_program(dep, before, profile_dir: str, chips: int, hits,
+                 compiles, peaks):
+    """The traced window's ``Run``: the launches, the program's spans,
+    marks and counter differences (``Run.program``) and the profile
+    read back.  Logs what the program's record says beyond the
+    per-layer metrics: the idle time split by the span the host was in,
+    the longest idle gaps outside ``wait_arrival`` and the device time
+    of each jitted program."""
+    from bench.lib import trace as tr
+    from bench.lib.readings import Program, Run
+    t0 = time.monotonic()
+    counters, h2d = (pt.less(a, b) for a, b in zip(pt.ledgers(dep), before))
+    trace = tr.load(profile_dir)
+    program = Program(list(dep.tracer.spans), dict(dep.tracer.marks),
+                      counters, h2d, pt.load(profile_dir), trace, chips)
+    reduced = tr.reduce(trace, chips)
+    log(phase="program", counters=counters, h2d=h2d,
+        psi_staged=sum(s.name == "window.stage" for s in program.spans),
+        spans=len(program.spans), spans_in_trace=len(program.profiled),
+        idle_by_span=pt.idle_by_span(trace, program.profiled, chips),
+        host_gaps=pt.idle_gaps(trace, program.profiled, chips,
+                               skip=("wait_arrival",)),
+        program_device_s=reduced.program_s)
+    log(phase="trace", seconds=time.monotonic() - t0,
+        window_s=reduced.window_s, busy_s=reduced.busy_s)
+    return Run(dep.arch, dep.model_cfg, dep.n_incr, dep.n_items,
+               list(dep.log.launches), hits, list(compiles), reduced,
+               peaks, program)
 
 
 def main(argv=None) -> int:
@@ -148,6 +184,8 @@ def main(argv=None) -> int:
     profile_dir = tempfile.mkdtemp(prefix="bench_trace_") \
         if args.trace else None
     if profile_dir:
+        before = pt.ledgers(dep)
+        dep.tracer.clear()
         jax.profiler.start_trace(profile_dir)
     ledger.recording = dep.log.recording = True
     slept0 = dep.clock.slept_s
@@ -186,17 +224,8 @@ def main(argv=None) -> int:
 
     run = None
     if args.trace:
-        from bench.lib import trace as tr
-        from bench.lib.readings import Run
-        reduced = None
-        if not args.rehearse:
-            t0 = time.monotonic()
-            reduced = tr.reduce(tr.load(profile_dir), work["chips"])
-            log(phase="trace", seconds=time.monotonic() - t0,
-                window_s=reduced.window_s, busy_s=reduced.busy_s)
-        run = Run(dep.model_cfg, dep.n_incr, dep.n_items,
-                  list(dep.log.launches), hits, list(ledger.names),
-                  reduced, peaks)
+        run = read_program(dep, before, profile_dir, work["chips"], hits,
+                           ledger.names, peaks)
         import shutil
         shutil.rmtree(profile_dir, ignore_errors=True)
 
@@ -205,19 +234,20 @@ def main(argv=None) -> int:
     # scores and tokens come to the host first
     tails = batched_tails(dep.log.launches)
     sample = check.collect(check.pick(completed, int(mix["check_sample"]),
-                                      args.seed, tails), dep.store)
-    dims, weights = dep.model_cfg, dep.weights
+                                      args.seed, tails), dep.store, dep.arch)
+    arch, dims, weights = dep.arch, dep.model_cfg, dep.weights
     del dep, reqs, completed, wreqs
     gc.collect()
 
     t0 = time.monotonic()
-    gaps = check.compare(dims, weights, sample)
+    gaps = check.compare(arch, dims, weights, sample)
     limit = float(config["check"]["score_gap_limit"])
     number = max([gaps.get("cached", 0.0), gaps.get("full", 0.0)])
     log(phase="check", seconds=time.monotonic() - t0, **gaps)
     if args.control:
-        for served in ("high", "bf16"):
-            ctl = check.compare(dims, weights, sample, served_mode=served)
+        for served in arch.MODES[1:]:
+            ctl = check.compare(arch, dims, weights, sample,
+                                served_mode=served)
             log(phase="control", served=served,
                 control_score_gap=max(ctl.get("cached", 0.0),
                                       ctl.get("full", 0.0)), **ctl)
@@ -227,11 +257,12 @@ def main(argv=None) -> int:
             "failed": len(lat) - sum(done), "device": device}
     if args.trace:
         line["metrics"] = spec.read_metrics(cell["per_layer"], run)
-        if run.trace is not None:
-            device["busy_s"] = run.trace.busy_s
-            device["window_s"] = run.trace.window_s
-            line["breakdown"] = {"device_ops": run.trace.device_ops,
-                                 "idle_gaps": run.trace.idle_gaps}
+        device["busy_s"] = run.trace.busy_s
+        device["window_s"] = run.trace.window_s
+        line["breakdown"] = {
+            "device_ops": run.trace.device_ops,
+            "idle_gaps": pt.idle_gaps(run.program.trace,
+                                      run.program.profiled, work["chips"])}
     else:
         units = {m["name"]: m["unit"] for m in cell["end_to_end"]}
         line["metrics"] = {k: {"value": v, "unit": units[k]}

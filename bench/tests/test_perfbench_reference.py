@@ -1,5 +1,6 @@
-"""The plain reference against the program's HSTU at the smoke size,
-and the bfloat16 control against each configuration's limit."""
+"""The plain reference of ``bench/models/hstu_gr.py`` against the
+program's HSTU at the smoke size, and the lower-precision control
+against each configuration's limit."""
 
 import json
 import sys
@@ -14,7 +15,8 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from bench.lib import check, harness, reference  # noqa: E402
+from bench.lib import check, harness  # noqa: E402
+from bench.models import hstu_gr  # noqa: E402
 
 
 def _request(rng, vocab, plen, n_incr, n_items):
@@ -26,9 +28,9 @@ def _request(rng, vocab, plen, n_incr, n_items):
 def test_reference_matches_the_model_at_highest_precision(grid, bucket):
     from repro.models import build_model, get_config
     cfg = get_config("hstu-gr", smoke=True)
-    dims = harness.model_dims(cfg)
+    dims = hstu_gr.dims(cfg)
     model = build_model(cfg)
-    w = reference.make_weights(dims, harness.seed_key(2**40 + 3))
+    w = hstu_gr.make_weights(dims, harness.seed_key(2**40 + 3))
     hist, incr, items = _request(np.random.default_rng(grid), cfg.vocab,
                                  grid, 16, 32)
     incr_, items_ = jnp.asarray(incr)[None], jnp.asarray(items)[None]
@@ -42,9 +44,9 @@ def test_reference_matches_the_model_at_highest_precision(grid, bucket):
                                items_)[0]
     toks = np.zeros(bucket, np.int32)
     toks[:grid] = hist
-    assert check.gap(hit, reference.rank_scores(
+    assert check.gap(hit, hstu_gr.rank_scores(
         dims, w, toks, grid, incr, items)) < 1e-5
-    assert check.gap(miss, reference.rank_scores(
+    assert check.gap(miss, hstu_gr.rank_scores(
         dims, w, tiled, bucket, incr, items)) < 1e-5
 
 
@@ -58,7 +60,7 @@ def test_lower_precision_control_fails_the_limit(config):
                       / f"{config}.json").read_text())
     dims = {k: v for k, v in cfg["model"].items() if k != "arch"}
     limit = cfg["check"]["score_gap_limit"]
-    w = reference.make_weights(dims, harness.seed_key(11))
+    w = hstu_gr.make_weights(dims, harness.seed_key(11))
     gaps = []
     for plen in (200, 256):
         hist, incr, items = _request(np.random.default_rng(plen),
@@ -66,7 +68,7 @@ def test_lower_precision_control_fails_the_limit(config):
         toks = np.zeros(256, np.int32)
         grid = -(-plen // 64) * 64
         toks[:grid] = np.resize(hist, grid)
-        want = reference.rank_scores(dims, w, toks, grid, incr, items, "f32")
-        got = reference.rank_scores(dims, w, toks, grid, incr, items, "high")
+        want = hstu_gr.rank_scores(dims, w, toks, grid, incr, items, "f32")
+        got = hstu_gr.rank_scores(dims, w, toks, grid, incr, items, "high")
         gaps.append(check.gap(got, want))
     assert max(gaps) > limit

@@ -1,6 +1,7 @@
 """The harness end to end on the CPU: a rehearsal on the smoke model
-prints a result that names the CPU, and the measuring path refuses to
-run without a TPU."""
+prints a result that names the CPU, a traced rehearsal prints the
+program's readings, and the measuring path refuses to run without a
+TPU."""
 
 import json
 import sys
@@ -41,3 +42,23 @@ def test_rehearsal_runs_the_harness_end_to_end(capsys):
                                     "slo_goodput_rps", "setup_s"}
     assert line["checks"]["score_gap"]["value"] <= \
         line["checks"]["score_gap"]["limit"]
+
+
+def test_traced_rehearsal_prints_the_program_readings(capsys):
+    """``--trace 1`` gives the service the program's tracer, and the
+    readers of its marks, spans and counters find them in ``Run.program``;
+    the profile is read back as on the chip (the CPU's operations stand
+    for the device's), so ``idle_unattributed_share`` and the breakdown
+    are there too.  Numbers of a rehearsal are not measurements."""
+    rc = run.main(["--workload", "L8k-zipf-steady", "--seed",
+                   str(2**31 + 29), "--seconds", "1", "--trace", "1",
+                   "--rehearse", "--rate", "10"])
+    line = _last_line(capsys)
+    assert rc == 0 and line["correct"] is True
+    metrics = line["metrics"]
+    for name in ("rank_wait_ms", "rank_deliver_ms", "rank_pad_share",
+                 "psi_host_ms", "psi_host_mb", "idle_unattributed_share"):
+        assert metrics[name]["value"] >= 0, name
+    assert metrics["rank_wait_ms"]["unit"] == "ms"
+    assert line["device"]["busy_s"] > 0
+    assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}

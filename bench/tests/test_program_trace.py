@@ -1,7 +1,9 @@
 """The program's spans and counters read back: the reduction of
-``bench/lib/program_trace.py`` on hand-made intervals and records, and
-a rehearsed traced run (``bench/trace_program.py``) on the CPU."""
+``bench/lib/program_trace.py`` on hand-made intervals and records, the
+six metric readers on a hand-made ``Run.program``, and a rehearsed
+traced run (``bench/run.py --trace 1``) on the CPU."""
 
+import json
 import sys
 from pathlib import Path
 
@@ -10,9 +12,11 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-from bench import trace_program  # noqa: E402
+from bench import run as bench_run  # noqa: E402
 from bench.lib import program_trace as pt  # noqa: E402
+from bench.lib import spec  # noqa: E402
 from bench.lib import trace as tr  # noqa: E402
+from bench.lib.readings import Program, Run  # noqa: E402
 from repro.core.tracing import Span  # noqa: E402
 
 NEW = ("rank_wait_ms", "rank_deliver_ms", "psi_host_ms", "psi_host_mb",
@@ -100,9 +104,18 @@ def test_readings_of_marks_spans_and_counters():
              Span("exec.rank", 0.0, 2.0, -1, {})]
     # union: 10 + 2 + 30 + 8 ms over two staged psi
     assert pt.psi_host_ms(spans) == pytest.approx(25.0)
-    h2d = {"d2h_bytes": 3e6, "mirror_bytes": 3e6, "materialized_bytes": 2e6,
-           "bytes_scattered": 4e6, "pages_scattered": 10}
-    assert pt.psi_host_mb(h2d, 2) == pytest.approx(6.0)
+    h2d = {"d2h_bytes": 3e6, "mirror_bytes": 1e6, "materialized_bytes": 3e6,
+           "bytes_scattered": 8e6, "device_sourced_bytes": 6e6,
+           "pages_scattered": 10}
+    # 3 MB pulled, 1 MB mirrored, 2 MB landed from the host; the 6 MB
+    # landed from the device cross nothing, and a device pool's dense
+    # copies are its pulls
+    assert pt.psi_host_bytes(h2d, device_pool=True) == 6e6
+    # a host pool's dense copies are host copies of their own
+    assert pt.psi_host_bytes(h2d, device_pool=False) == 9e6
+    h2d["psi_host_bytes"] = 6e6
+    assert pt.psi_host_mb(h2d, 2) == pytest.approx(3.0)
+    assert pt.psi_host_mb({"d2h_bytes": 3e6}, 2) is None
     # one launch of three rows (40, 70, 90 tokens) padded to 4 x 128
     counters = {"rank_tokens_launched": 512, "rank_tokens_real": 200}
     assert pt.rank_pad_share(counters) == pytest.approx(
@@ -111,21 +124,93 @@ def test_readings_of_marks_spans_and_counters():
     assert pt.psi_host_ms([]) is None and pt.psi_host_mb(h2d, 0) is None
 
 
-def test_rehearsed_traced_run_reports_the_program_metrics(tmp_path):
-    args = trace_program.run.parse_args(
-        ["--workload", "L8k-zipf-steady", "--seed", str(2**31 + 5),
-         "--seconds", "1", "--rehearse", "--rate", "10"])
-    line = trace_program.traced_run(args, str(tmp_path))
-    assert line["platform"] == "cpu" and line["completed"] > 5
+def test_psi_host_bytes_of_a_device_pool_count_each_crossing_once():
+    """A landing from the device crosses nothing, a spill's gather is
+    one pull (not a pull and a copy), and a landing put from the host
+    counts its bytes."""
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.core.paging import DevicePagePool, PageLayout, PagedPsi
+    layout = PageLayout(page_tokens=4, slabs=2, token_bytes=2 * 4 * 4)
+    pool = DevicePagePool(8, layout.page_bytes)
+    table = np.array([[0, 1], [2, 3]], np.int32)
+    psi = jnp.arange(64, dtype=jnp.float32).reshape(1, 1, 8, 2, 4)
+    entry = 4 * layout.page_bytes
+    pool.land([0, 1, 2, 3], table, (psi, psi + 1))
+    assert pt.psi_host_bytes(pool.h2d, device_pool=True) == 0
+    k, _ = PagedPsi(table, 8, layout, None, pool=pool).materialize()
+    np.testing.assert_array_equal(k, np.asarray(psi))
+    assert pt.psi_host_bytes(pool.h2d, device_pool=True) == entry
+    pool.land([4, 5, 6, 7], table + 4, (np.asarray(psi),) * 2)
+    assert pt.psi_host_bytes(pool.h2d, device_pool=True) == 2 * entry
+
+
+def _program(traced=True):
+    """Three completed requests, two psi staged, one padded launch; the
+    ``_trace`` window with 3 s of program spans over idle time."""
+    marks = {1: {"due": 0.0, "launch": 0.2, "launched": 0.25, "sink": 0.4},
+             2: {"due": 1.0, "launch": 1.1, "launched": 1.15, "sink": 1.2},
+             3: {"due": 2.0, "launch": 2.6, "launched": 2.7, "sink": 2.75}}
+    spans = [Span("window.stage", 0.0, 0.010, -1, {}),
+             Span("window.scatter", 0.010, 0.012, -1, {}),
+             Span("dram.spill", 0.5, 0.530, -1, {}),
+             Span("window.materialize", 0.505, 0.525, 2, {}),
+             Span("window.stage", 1.0, 1.008, -1, {})]
+    h2d = {"d2h_bytes": 3e6, "materialized_bytes": 3e6,
+           "bytes_scattered": 8e6, "device_sourced_bytes": 6e6,
+           "psi_host_bytes": 5e6, "pages_scattered": 10}
+    counters = {"rank_tokens_launched": 512, "rank_tokens_real": 200}
+    profiled = [tr.Span("relay.event", 3.0, 6.0, {}),
+                tr.Span("window.stage", 4.0, 5.0, {})]
+    return Program(spans, marks, counters, h2d, profiled,
+                   _trace() if traced else None)
+
+
+@pytest.mark.parametrize("name,want", [
+    ("rank_wait_ms", 200.0), ("rank_deliver_ms", 50.0),
+    ("rank_pad_share", 100 * (1 - 200 / 512)), ("psi_host_ms", 25.0),
+    ("psi_host_mb", 2.5), ("idle_unattributed_share", 40.0)])
+def test_program_readers_read_the_runs_program(name, want):
+    read = spec.metric_reader(name)
+    run = Run(None, {}, 64, 512, [], [], [], program=_program())
+    assert read(run) == pytest.approx(want)
+    assert read(Run(None, {}, 64, 512, [], [], [])) is None
+    untraced = read(Run(None, {}, 64, 512, [], [], [],
+                        program=_program(traced=False)))
+    if name == "idle_unattributed_share":
+        assert untraced is None
+    else:
+        assert untraced == pytest.approx(want)
+
+
+def test_rehearsed_traced_run_reports_the_program_metrics(
+        tmp_path, capsys, monkeypatch):
+    import shutil
+    import tempfile
+    # keep the profile to read it again below
+    monkeypatch.setattr(tempfile, "mkdtemp", lambda **kw: str(tmp_path))
+    monkeypatch.setattr(shutil, "rmtree", lambda *a, **kw: None)
+    rc = bench_run.main(["--workload", "L8k-zipf-steady", "--seed",
+                         str(2**31 + 5), "--seconds", "1", "--trace", "1",
+                         "--rehearse", "--rate", "10"])
+    out = [json.loads(x) for x in capsys.readouterr().out.splitlines()
+           if x.startswith("{")]
+    line = out[-1]
+    window, = (d for d in out if d.get("phase") == "window")
+    prog, = (d for d in out if d.get("phase") == "program")
+    assert rc == 0 and line["device"]["platform"] == "cpu"
+    assert window["completed"] > 5
     for name in NEW:
-        assert line["metrics"][name] is not None, name
+        assert line["metrics"][name]["value"] is not None, name
     assert {"relay_hit_share", "rank_device_ms", "device_idle_share",
             "device_idle_host_share"} <= set(line["metrics"])
-    assert line["counters"]["rank_rows"] >= line["completed"]
-    assert line["program_spans_in_trace"] == line["spans"]
+    assert prog["counters"]["rank_rows"] >= window["completed"]
+    assert prog["spans_in_trace"] == prog["spans"]
+    assert prog["psi_staged"] > 0
+    assert sum(prog["idle_by_span"].values()) > 0
     # the device time is named by the served path's programs
     assert {"jit_prefill", "jit_rank_pages", "jit_pool_scatter"} <= \
-        set(line["program_device_s"])
+        set(prog["program_device_s"])
 
     # the benchmark's readings do not move when a loader keeps the
     # program's spans beside its own
@@ -135,8 +220,9 @@ def test_rehearsed_traced_run_reports_the_program_metrics(tmp_path):
                                          "window.stage", "relay.sink"}
     assert not {s.name for s in trace.spans} & set(pt.PROGRAM_SPANS)
     alone = tr.reduce(trace)
-    beside = tr.reduce(tr.Trace(trace.ops, trace.spans + program))
-    for field in ("window_s", "busy_s", "idle_host_s", "span_device_s",
+    beside = tr.reduce(tr.Trace(trace.ops, trace.spans + program,
+                                trace.programs))
+    for field in ("window_s", "busy_s", "idle_host_s", "program_s",
                   "device_ops"):
         assert getattr(alone, field) == getattr(beside, field), field
-    assert alone.span_device_s
+    assert alone.program_s
