@@ -5,7 +5,9 @@ on the wall clock.
 The deployment is what ``repro.launch.serve.build_live`` builds for
 ``--batched --device-pool``: ``RelayGRService`` over ``RelayRuntime``,
 the registered ``batched`` executor and the device-resident paged
-window of 64-token pages, 2 special + 2 normal instances.  It differs
+window of 64-token pages: the configuration's special and normal
+instances, one ``batched`` executor per chip, the instances dealt over
+the chips in turn.  It differs
 in what the benchmark must own: the weights (made here from the seed),
 the behaviour store's length distribution (the traffic mix's), the
 window size (the configuration's share of free device memory) and the
@@ -185,10 +187,10 @@ def window_bytes(devices, instances: int, share: float) -> int:
 def warm(dep: Deployment, prefix_lens: List[int], threads: int = 4
          ) -> Dict[str, int]:
     """Compile (or load from the persistent cache) every program the
-    traffic can reach: the rank programs of each bucket and batch size
-    and the pool's landing and gather of each prefill grid length (the
-    executor's own ``warmup``), and the prefill of each 64-token grid
-    length and batch size."""
+    traffic can reach, on each executor's device: the rank programs of
+    each bucket and batch size and the pool's landing and gather of
+    each prefill grid length (the executor's own ``warmup``), and the
+    prefill of each 64-token grid length and batch size."""
     from repro.core.types import UserMeta
     from repro.serving.batching import bucket_of, prefill_grid
     ex0 = dep.executors[0]
@@ -197,10 +199,16 @@ def warm(dep: Deployment, prefix_lens: List[int], threads: int = 4
                     for b in range(1, max_batch + 1)})
     pool_pages = dep.window_bytes // ex0.page_layout.page_bytes
     counts = {"buckets": len({bucket_of(n) for n in prefix_lens})}
-    for ex in dep.executors:
+
+    def rank_and_pool(ex):
         ex.warmup(prefix_lens, batch_sizes=range(1, max_batch + 1),
                   incr_len=dep.n_incr, n_items=dep.n_items,
                   pool_pages=pool_pages)
+
+    # one thread per executor: each compiles (or loads) its own device's
+    # programs, so chips warm side by side
+    with ThreadPoolExecutor(len(dep.executors)) as pool:
+        list(pool.map(rank_and_pool, dep.executors))
     grids = sorted({prefill_grid(n) for n in prefix_lens})
     counts["prefill_grids"] = len(grids)
 

@@ -103,7 +103,7 @@ def load(profile_dir: str) -> List[tr.Span]:
         raise FileNotFoundError(f"no .xplane.pb under {profile_dir}")
     out = []
     for plane in ProfileData.from_file(paths[-1]).planes:
-        if plane.name != "/host:CPU":
+        if plane.name != tr.HOST:
             continue
         for line in plane.lines:
             for ev in line.events:

@@ -12,11 +12,14 @@ in.  On a TPU the operations are the events of each device plane's
 ``XLA Ops`` line and the programs those of its ``XLA Modules`` line
 (``jit_<name>(<id>)``); on the CPU backend, which has no device plane,
 both are the host events that carry an ``hlo_op`` argument, the
-program named by their ``hlo_module``.
+program named by their ``hlo_module``, and all its devices read as one.
 
 Busy time is the union of operation intervals, so overlapping
 operations count once.  Everything else here is interval arithmetic on
-that union.
+that union.  Over several devices each device is reduced on its own: a
+program's device seconds are the union of its executions within each
+device, summed over the devices, since two chips that run it at once
+each spend that time.
 """
 
 from __future__ import annotations
@@ -31,6 +34,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 Interval = Tuple[float, float]           # seconds, on the trace's clock
 
 SPANS = ("bench_window", "prefill", "rank", "scatter", "wait_arrival")
+HOST = "/host:CPU"
 
 
 @dataclasses.dataclass
@@ -102,7 +106,7 @@ def load(profile_dir: str) -> Trace:
                         (s, e, ev.name.split("(")[0]))
                 elif device:
                     ops[plane.name].append((s, e, _op_name(ev)))
-                elif plane.name == "/host:CPU":
+                elif plane.name == HOST:
                     if ev.name in SPANS:
                         spans.append(Span(ev.name, s, e, _stats(ev)))
                     elif ev.duration_ns > 0:
@@ -112,8 +116,8 @@ def load(profile_dir: str) -> Trace:
                             host_programs.append(
                                 (s, e, str(st.get("hlo_module", ""))))
     if not ops and host_ops:
-        ops["/host:CPU"] = host_ops
-        programs["/host:CPU"] = host_programs
+        ops[HOST] = host_ops
+        programs[HOST] = host_programs
     return Trace(dict(ops), spans, dict(programs))
 
 
@@ -186,10 +190,12 @@ def gaps(merged: Sequence[Interval], lo: float, hi: float
 class Reduced:
     window_s: float
     busy_s: float                     # mean over the devices used
+    device_busy_s: Dict[str, float]   # device -> its busy seconds
     idle_host_s: float                # idle and the host not waiting
     program_s: Dict[str, float]       # jitted program -> device seconds
     device_ops: List[list]            # [[op, seconds], ...] top 10
     idle_gaps: List[list]             # [[host span, seconds], ...] top 10
+    n_devices: int = 1                # devices the cell uses
 
 
 def reduce(trace: Trace, n_devices: int = 1,
@@ -200,14 +206,17 @@ def reduce(trace: Trace, n_devices: int = 1,
             for d in names}
     wait = union([(s.start, s.end) for s in trace.spans
                   if s.name == "wait_arrival"])
-    # each program's executions on the devices used, as one union
-    runs: Dict[str, list] = defaultdict(list)
+    # each program's executions: a union within each device, summed
+    # over the devices
+    program_s: Dict[str, float] = defaultdict(float)
     for d in names:
+        runs: Dict[str, list] = defaultdict(list)
         for s, e, name in trace.programs.get(d, ()):
             runs[name].append((s, e))
-    program_s = {name: length(clip(union(ivs), lo, hi))
-                 for name, ivs in runs.items()}
-    busy_s = sum(covered(busy[d], lo, hi) for d in names) / len(names)
+        for name, ivs in runs.items():
+            program_s[name] += length(clip(union(ivs), lo, hi))
+    device_busy_s = {d: covered(busy[d], lo, hi) for d in names}
+    busy_s = sum(device_busy_s.values()) / len(names)
     # idle while the host was not waiting for an arrival: the idle time
     # less the waiting that fell on idle device time
     idle_host = 0.0
@@ -226,12 +235,16 @@ def reduce(trace: Trace, n_devices: int = 1,
                 per_op[op] += min(e, hi) - max(s, lo)
     top = sorted(per_op.items(), key=lambda kv: -kv[1])[:10]
     named.sort(key=lambda x: -x[0])
-    return Reduced(window_s=hi - lo, busy_s=busy_s, idle_host_s=idle_host,
+    return Reduced(window_s=hi - lo, busy_s=busy_s,
+                   device_busy_s=device_busy_s, idle_host_s=idle_host,
                    program_s={k: v for k, v in sorted(
                        program_s.items(), key=lambda kv: -kv[1]) if v > 0},
                    device_ops=[[op, t] for op, t in top],
                    idle_gaps=[[_host_state(trace.spans, g), t]
-                              for t, g in named[:10]])
+                              for t, g in named[:10]],
+                   # the CPU backend's devices share one host plane
+                   n_devices=1 if HOST in busy else max(n_devices,
+                                                        len(names)))
 
 
 def _host_state(spans: Sequence[Span], gap: Interval) -> str:

@@ -125,8 +125,13 @@ def read_program(dep, before, profile_dir: str, chips: int, hits,
         host_gaps=pt.idle_gaps(trace, program.profiled, chips,
                                skip=("wait_arrival",)),
         program_device_s=reduced.program_s)
+    # the host loop's own time: the window less its waits for arrivals
+    waiting = tr.union([(s.start, s.end) for s in trace.spans
+                        if s.name == "wait_arrival"])
     log(phase="trace", seconds=time.monotonic() - t0,
-        window_s=reduced.window_s, busy_s=reduced.busy_s)
+        window_s=reduced.window_s, busy_s=reduced.busy_s,
+        device_busy_s=reduced.device_busy_s,
+        host_wait_s=tr.covered(waiting, *trace.window()))
     return Run(dep.arch, dep.model_cfg, dep.n_incr, dep.n_items,
                list(dep.log.launches), hits, list(compiles), reduced,
                peaks, program)
@@ -166,6 +171,9 @@ def main(argv=None) -> int:
     ledger = CompileLedger().install()
     dep = harness.build(config, mix, args.seed, args.rehearse,
                         annotate=bool(args.trace))
+    log(phase="placement", instances={
+        name: str(inst.executor.device) for name, inst in
+        dep.svc.instances.items()})
     lens = [dep.store.prefix_len(u) for _, u in window + warmup]
     t0 = time.monotonic()
     warmed = harness.warm(dep, lens)

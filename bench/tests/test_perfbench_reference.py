@@ -50,7 +50,8 @@ def test_reference_matches_the_model_at_highest_precision(grid, bucket):
         dims, w, tiled, bucket, incr, items)) < 1e-5
 
 
-@pytest.mark.parametrize("config", ["hstu_gr-L8k", "hstu_gr-L2k"])
+@pytest.mark.parametrize("config", ["hstu_gr-L8k", "hstu_gr-L2k",
+                                    "hstu_gr-L8k-x4"])
 def test_lower_precision_control_fails_the_limit(config):
     """The control (the reference at three bfloat16 passes, the next
     precision below the float32 the configuration states) at the
