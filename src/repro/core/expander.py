@@ -20,7 +20,11 @@ pool into a dense host copy, and a reload streams only the pages the
 window is missing — a partially evicted entry (tail pages freed under
 pressure) RESUMES from its resident head instead of restarting, with
 ``CacheEntry.reload_tokens`` carrying the remaining transfer so the
-executor prices exactly the missing pages.
+executor prices exactly the missing pages.  The consume-time spill
+(``spill(..., defer_pull=True)``) books the copy at once but leaves a
+device pool's pull running: the entry's value is a ``PendingPull``
+until the runtime settles it while idle or a read here (lookup,
+reload, handoff, demotion) settles it first.
 """
 
 from __future__ import annotations
@@ -30,7 +34,7 @@ from collections import OrderedDict
 from typing import Any, Dict, List, Optional, Tuple
 
 from .cache import CacheEntry, HBMCacheStore, tenant_ledger
-from .paging import PagedPsi
+from .paging import PagedPsi, PendingPull
 from .types import CacheState
 
 
@@ -138,9 +142,12 @@ class DRAMExpander:
         return None
 
     # --- spill (after consumption, off the critical path) -------------------
-    def spill(self, entry: CacheEntry) -> bool:
+    def spill(self, entry: CacheEntry, defer_pull: bool = False) -> bool:
         """Store ``entry`` in the DRAM tier; returns whether it fit
-        (callers use this for their own spill accounting)."""
+        (callers use this for their own spill accounting).
+        ``defer_pull``: a device pool's pull is started, not waited for
+        (``PagedPsi.materialize_async``); the entry is booked at once
+        and its value settles later."""
         if entry.value is None:
             # a partially evicted paged entry finally left the window:
             # its stale head is worthless, but the full DRAM copy made
@@ -160,14 +167,17 @@ class DRAMExpander:
         if isinstance(entry.value, PagedPsi):
             # psi leaves the pool: the DRAM copy is a dense host pytree,
             # detached from page ids the window is free to recycle
+            psi = entry.value
             entry = dataclasses.replace(
-                entry, value=entry.value.materialize(), page_table=None,
-                tokens_resident=entry.prefix_len)
+                entry, value=(psi.materialize_async() if defer_pull
+                              else psi.materialize()),
+                page_table=None, tokens_resident=entry.prefix_len)
         elif entry.page_table is not None:
             entry = dataclasses.replace(entry, page_table=None,
                                         tokens_resident=entry.prefix_len)
         if entry.user_id in self.entries:
             stale = self._remove(entry.user_id)
+            _discard(stale)
             self.stats["evictions"] += 1       # replaced same-user copy
             self._tbump(stale.tenant, "evictions")
         used = (self.tenant_used.get(int(entry.tenant), 0)
@@ -182,10 +192,12 @@ class DRAMExpander:
                 self.stats["cross_tenant_evictions"] += 1
             self.stats["lru_evictions"] += 1
             self._tbump(_.tenant, "lru_evictions")
-            if self.demote_sink is not None and self.demote_sink(_):
+            if (self.demote_sink is not None
+                    and self.demote_sink(settled(_))):
                 self.stats["demotions"] += 1   # spilled DOWN, not dropped
                 self._tbump(_.tenant, "demotions")
             else:
+                _discard(_)
                 self.stats["evictions"] += 1
                 self._tbump(_.tenant, "evictions")
             used = (self.tenant_used.get(int(entry.tenant), 0)
@@ -200,6 +212,7 @@ class DRAMExpander:
             self._tbump(entry.tenant, "spills")
             self._tbump(entry.tenant, "inserts")
             return True
+        _discard(entry)
         return False
 
     def lookup(self, user_id: int) -> Optional[CacheEntry]:
@@ -210,6 +223,7 @@ class DRAMExpander:
             self.entries.move_to_end(user_id)  # LRU touch
             self.stats["dram_hits"] += 1
             self._tbump(e.tenant, "dram_hits")
+            settled(e)
         return e
 
     def _remove(self, user_id: int) -> CacheEntry:
@@ -228,6 +242,7 @@ class DRAMExpander:
             self._remove(user_id)
             self.stats["handoffs"] = self.stats.get("handoffs", 0) + 1
             self._tbump(e.tenant, "handoffs")
+            settled(e)
         return e
 
     # --- pseudo-pre-infer --------------------------------------------------
@@ -279,6 +294,7 @@ class DRAMExpander:
         e = self.entries.get(user_id)
         evicted: List[CacheEntry] = []
         if e is not None:
+            settled(e)
             e.reload_tokens = None
             evicted = hbm.insert(user_id, e.value, e.nbytes, now,
                                  prefix_len=e.prefix_len, spans=e.spans,
@@ -311,3 +327,19 @@ class DRAMExpander:
 
     def finish(self, user_id: int):
         self.flight.end(user_id)
+
+
+def settled(entry: CacheEntry, idle: bool = False) -> CacheEntry:
+    """``entry`` with its copy on the host: a deferred pull is finished
+    (counted idle or forced, if it was still running) and the value
+    becomes the dense pair."""
+    if isinstance(entry.value, PendingPull):
+        entry.value = entry.value.result(idle)
+    return entry
+
+
+def _discard(entry: CacheEntry) -> None:
+    """A copy leaving the tier unread drops its deferred pull."""
+    if isinstance(entry.value, PendingPull):
+        entry.value.discard()
+        entry.value = None

@@ -34,14 +34,18 @@ pages it holds: dense psi lands in it with one donated
 ``.at[pages].set(...)`` update on the device (``DevicePagePool.land``),
 rank launches pass the pool by reference (zero per-launch re-ship), and
 ``PagedPsi.materialize`` gathers pages back on the device and pulls the
-dense copy with one device-to-host transfer.  The ``h2d`` ledger on
-every pool accounts the traffic either way.
+dense copy with one device-to-host transfer.  ``materialize_async``
+dispatches the same gather and starts the transfer without waiting for
+it: the ``PendingPull`` it returns settles later, and a pool keeps at
+most one unsettled.  The ``h2d`` ledger on every pool accounts the
+traffic either way.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
+import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -93,7 +97,8 @@ class PageLayout:
 
 H2D_KEYS = ("bytes_scattered", "pages_scattered", "scatters",
             "launch_reships", "reshipped_bytes", "d2h_bytes",
-            "mirror_bytes", "materialized_bytes", "device_sourced_bytes")
+            "mirror_bytes", "materialized_bytes", "device_sourced_bytes",
+            "pulls_deferred", "pulls_settled_idle", "pulls_settled_forced")
 
 
 class PagePool:
@@ -127,7 +132,11 @@ class PagePool:
         # off the device (a host pool's staging pull, a device pool's
         # materialize), ``mirror_bytes`` of pages written into a host
         # page buffer, ``materialized_bytes`` of dense host copies
-        # gathered out of the pool (spill, evict, handoff).
+        # gathered out of the pool (spill, evict, handoff).  A device
+        # pool's deferred pulls: ``pulls_deferred`` started without
+        # waiting, each settled once, either while the event loop had
+        # nothing due (``pulls_settled_idle``) or because something
+        # needed it first (``pulls_settled_forced``).
         self.h2d = dict.fromkeys(H2D_KEYS, 0)
 
     @property
@@ -293,6 +302,8 @@ class DevicePagePool(PagePool):
         self.device_buffer = None           # lazily shaped, jax array
         self.device = None                  # where the buffer lives
         self.head_shape: Optional[Tuple[int, int]] = None   # (H, D)
+        self.pending: Optional["PendingPull"] = None   # unsettled pull
+        self.settle_s = 0.0                 # the last settle's seconds
 
     def ensure_device(self, row_shape: Tuple[int, int], dtype, device=None):
         """Create the resident buffer, ``(n_pages + 1, *row_shape)``, on
@@ -375,11 +386,73 @@ class DevicePagePool(PagePool):
         D)``, of the pages of ``table`` ``(slabs, n)``: gathered on the
         device, pulled with one device-to-host copy, no host copy after
         it."""
+        return self._split(np.asarray(self._dispatch_gather(table)))
+
+    def gather_async(self, table: np.ndarray) -> "PendingPull":
+        """``gather`` without the wait: the gather is dispatched now, so
+        it reads the pages as they are before any later landing
+        recycles them, and its device-to-host copy starts; the returned
+        ``PendingPull`` gives the same ``(K, V)``."""
+        out = self._dispatch_gather(table)
+        out.copy_to_host_async()
+        self.h2d["pulls_deferred"] += 1
+        self.pending = PendingPull(self, out)
+        return self.pending
+
+    def settle(self) -> None:
+        """Finish the pending pull, if any, as a forced settle."""
+        if self.pending is not None:
+            self.pending.result()
+
+    def _dispatch_gather(self, table: np.ndarray):
         assert self.device_buffer is not None, "no page data landed"
-        out = np.asarray(_gather_jit()(self.device_buffer, table))
+        # at most one dense copy waits in device memory per pool
+        self.settle()
+        out = _gather_jit()(self.device_buffer, table)
         self.h2d["d2h_bytes"] += out.nbytes
+        return out
+
+    def _split(self, out: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         out = out.reshape(out.shape[:4] + self.head_shape)
         return out[0], out[1]
+
+
+class PendingPull:
+    """A device pool's dense copy on its way to the host
+    (``DevicePagePool.gather_async``).  ``result`` waits for the copy
+    under the ``window.materialize`` span, counts the settle as idle or
+    forced in the pool's ``h2d`` ledger, drops the device array and
+    gives the dense host ``(K, V)`` that ``gather`` would have; later
+    calls give the same pair at once.  ``discard`` drops the device
+    array, read or not."""
+
+    def __init__(self, pool: DevicePagePool, out):
+        self.pool = pool
+        self.nbytes = int(out.nbytes)
+        self._out = out                 # the device array until settled
+        self._kv = None
+
+    @property
+    def settled(self) -> bool:
+        return self._out is None
+
+    def result(self, idle: bool = False) -> Tuple[np.ndarray, np.ndarray]:
+        if self._out is not None:
+            pool = self.pool
+            with pool.tracer.span("window.materialize", bytes=self.nbytes):
+                t0 = time.perf_counter()
+                out = np.asarray(self._out)
+                pool.settle_s = time.perf_counter() - t0
+            self._kv = pool._split(out)
+            pool.h2d["pulls_settled_idle" if idle
+                     else "pulls_settled_forced"] += 1
+            self.discard()
+        return self._kv
+
+    def discard(self) -> None:
+        self._out = None
+        if self.pool.pending is self:
+            self.pool.pending = None
 
 
 class PagedPsi:
@@ -423,6 +496,8 @@ class PagedPsi:
         nbytes = slabs * np_ * self.layout.page_bytes
         pool = self.pool
         tracer = pool.tracer if pool is not None else OFF
+        if isinstance(pool, DevicePagePool):
+            pool.settle()           # a pending pull settles in its own span
         with tracer.span("window.materialize", bytes=nbytes):
             if self.buffer is None and isinstance(pool, DevicePagePool):
                 out = pool.gather(self.table)
@@ -439,6 +514,18 @@ class PagedPsi:
         if pool is not None:
             pool.h2d["materialized_bytes"] += nbytes
         return out
+
+    def materialize_async(self) -> Any:
+        """``materialize`` with the device-to-host copy left running: on
+        a device pool a ``PendingPull`` of the dense copy; elsewhere the
+        copy itself (a host pool's is a memcpy with nothing to
+        overlap)."""
+        pool = self.pool
+        if self.buffer is not None or not isinstance(pool, DevicePagePool):
+            return self.materialize()
+        pull = pool.gather_async(self.table)
+        pool.h2d["materialized_bytes"] += pull.nbytes
+        return pull
 
 
 def slice_into_pages(buffer: np.ndarray, table: np.ndarray, value: Any,

@@ -215,5 +215,5 @@ class NullExpander(DRAMExpander):
             dram_budget_bytes=0.0,
             max_reload_concurrency=cfg.max_reload_concurrency))
 
-    def spill(self, entry) -> bool:
+    def spill(self, entry, defer_pull: bool = False) -> bool:
         return False

@@ -68,8 +68,8 @@ from .clock import Clock, VirtualClock, WallClock
 from .coldstore import ColdStore, ColdStoreConfig
 from .costmodel import GRCostModel
 from .executors import Executor, get_executor
-from .expander import DRAMExpander, ExpanderConfig
-from .paging import H2D_KEYS, DevicePagePool, PageLayout
+from .expander import DRAMExpander, ExpanderConfig, settled
+from .paging import H2D_KEYS, DevicePagePool, PageLayout, PendingPull
 from .policies import make_expander, make_router, make_trigger
 from .topology import (ClusterTopology, Host, make_prefill_hosts,
                        stripe_hosts)
@@ -736,6 +736,10 @@ class RelayRuntime:
         self._next_normal = nn
         self.events: list = []
         self.records: List[Record] = []
+        # DRAM entries whose consume-time pull may still run, oldest
+        # first: drain settles them while it would otherwise wait for
+        # the next event
+        self._pulls: deque = deque()
         self._seq = itertools.count()
         self._req_ids = itertools.count()
         self.now = 0.0
@@ -775,18 +779,38 @@ class RelayRuntime:
 
     def drain(self) -> None:
         tracer = self.tracer
-        while self.events:
-            t, _, kind, kw = heapq.heappop(self.events)
-            self.now = t
-            self.clock.advance(t)
-            handler = getattr(self, f"_on_{kind}")
-            if tracer.on:
-                with tracer.span("relay.event", kind=kind,
-                                 late_ms=(self.clock.now() - t) * 1e3,
-                                 **_event_ids(kw)):
+        try:
+            while self.events:
+                t, _, kind, kw = heapq.heappop(self.events)
+                self.now = t
+                if self._pulls:
+                    self._settle_idle(t)
+                self.clock.advance(t)
+                handler = getattr(self, f"_on_{kind}")
+                if tracer.on:
+                    with tracer.span("relay.event", kind=kind,
+                                     late_ms=(self.clock.now() - t) * 1e3,
+                                     **_event_ids(kw)):
+                        handler(t, **kw)
+                else:
                     handler(t, **kw)
-            else:
-                handler(t, **kw)
+        finally:
+            # the heap is empty or the clock closed the window: nothing
+            # is left pending behind the loop
+            while self._pulls:
+                settled(self._pulls.popleft())
+
+    def _settle_idle(self, t: float) -> None:
+        """Settle deferred DRAM pulls, oldest first, while the event due
+        at ``t`` is further off than the pull's pool last took to
+        settle one."""
+        pulls = self._pulls
+        while pulls:
+            pull = pulls[0].value
+            if (isinstance(pull, PendingPull) and not pull.settled
+                    and t - self.clock.now() <= pull.pool.settle_s):
+                return
+            settled(pulls.popleft(), idle=True)
 
     def run(self, arrivals: Iterable[Tuple[float, UserMeta]]
             ) -> Dict[str, float]:
@@ -1850,17 +1874,23 @@ class RelayRuntime:
                        result: RankResult) -> None:
         """A rank's scores are in: spill its consumed psi to the DRAM
         tier (a proactive copy for short-term cross-request reuse),
-        record it and hand the scores to the sink."""
+        record it and hand the scores to the sink.  A device pool's
+        pull of the copy is only started here; ``drain`` settles it
+        after the sink, while the loop waits."""
         tracer = self.tracer
         rec: Record = job["rec"]
         e = inst.hbm.consume(result.user_id)
         if e is not None and inst.expander.cfg.dram_budget_bytes > 0:
             with tracer.span("dram.spill", uid=result.user_id,
                              bytes=e.nbytes):
-                spilled = inst.expander.spill(dataclasses.replace(e))
+                spilled = inst.expander.spill(dataclasses.replace(e),
+                                              defer_pull=True)
             if spilled:
                 inst.stats["spills"] += 1
                 e.dram_backed = True       # eligible for partial eviction
+                d = inst.expander.entries[result.user_id]
+                if isinstance(d.value, PendingPull):
+                    self._pulls.append(d)
         rec.t_done = t
         rec.rank_stage_ms = rec.queue_ms + rec.load_ms + rec.rank_ms
         self.records.append(rec)

@@ -32,7 +32,11 @@ of one request):
                        bytes)
     window.materialize a dense host copy gathered out of the pool
                        (bytes); a device pool gathers on the device and
-                       pulls once
+                       pulls once.  The consume-time spill's pull is
+                       only started inside dram.spill: this span is its
+                       settle, after the sink, in RelayRuntime.drain
+                       while the loop would wait, or inside whatever
+                       needed the copy first
     dram.spill         psi copied into the DRAM expander (uid, bytes)
     relay.sink         scores handed to the request's sink (req, uid)
 
@@ -41,7 +45,11 @@ Marks: ``due`` (the rank request fell due), ``launch`` and ``launched``
 
 Counters stay on the ledgers that already exist and count whether or
 not tracing is on: the page pool's ``h2d`` and the executor's
-``counters``.
+``counters``.  Of ``h2d``, ``pulls_deferred`` counts the consume-time
+spill pulls a device pool started without waiting,
+``pulls_settled_idle`` those settled while the loop had nothing due
+and ``pulls_settled_forced`` those a read, another gather on the pool
+or the end of a drain settled first.
 """
 
 from __future__ import annotations

@@ -35,7 +35,8 @@ from repro.core import (BatchingConfig, ClusterConfig, DevicePagePool,
                         GRCostModel, HitKind, PageLayout, TriggerConfig,
                         UserMeta, get_executor, relay_config)
 from repro.core.cache import PagedHBMStore, kv_nbytes
-from repro.core.paging import PagedPsi
+from repro.core.expander import DRAMExpander, ExpanderConfig
+from repro.core.paging import PagedPsi, PendingPull
 from repro.core.runtime import RelayRuntime
 from repro.models import get_config
 
@@ -397,3 +398,190 @@ def test_pool_fills_on_its_own_device():
         capture_output=True, text=True, env=env, timeout=120)
     assert res.returncode == 0, res.stderr
     assert res.stdout.strip() == "ok"
+
+
+# ---------------------------------------------------------------------------
+# deferred DRAM-spill pulls (PagedPsi.materialize_async / PendingPull)
+# ---------------------------------------------------------------------------
+
+
+def _spilled(dram, store, uid, now=0.0):
+    """Insert ``uid``'s psi, consume it and spill it with its pull left
+    running, as the runtime does when a rank completes; returns the
+    dense copy the synchronous path gives for the same pages."""
+    import dataclasses
+    v = _kv(uid, _tokens_of(uid))
+    store.insert(uid, v, kv_nbytes(v), now, prefix_len=_tokens_of(uid))
+    e = store.consume(uid)
+    ref = e.value.materialize()
+    assert dram.spill(dataclasses.replace(e), defer_pull=True)
+    e.dram_backed = True
+    return ref
+
+
+def _same(a, b):
+    return all(x.shape == y.shape and x.dtype == y.dtype
+               and x.tobytes() == y.tobytes() for x, y in zip(a, b))
+
+
+def _conserved(dram):
+    s = dram.stats
+    return s["inserts"] == (len(dram.entries) + s["evictions"]
+                            + s["demotions"] + s["handoffs"]
+                            + s["promotions"])
+
+
+def test_deferred_pull_settles_to_the_synchronous_copy():
+    store = _store(True)
+    pool = store.pool
+    v = _kv(1, _tokens_of(1))
+    store.insert(1, v, kv_nbytes(v), 0.0, prefix_len=_tokens_of(1))
+    psi = store.consume(1).value
+    pull = psi.materialize_async()
+    assert isinstance(pull, PendingPull) and pool.pending is pull
+    assert not pull.settled
+    assert pool.h2d["pulls_deferred"] == 1
+    assert pool.h2d["d2h_bytes"] == pool.h2d["materialized_bytes"] \
+        == pull.nbytes == LAYOUT.entry_bytes(_tokens_of(1))
+    kv = pull.result()
+    assert pull.settled and pool.pending is None and pull.result() is kv
+    assert pool.h2d["pulls_settled_forced"] == 1
+    assert pool.h2d["pulls_settled_idle"] == 0
+    assert all(isinstance(a, np.ndarray) for a in kv)
+    assert _same(kv, psi.materialize())
+
+
+def test_deferred_pull_on_a_host_pool_is_the_synchronous_copy():
+    store = _store(False)
+    v = _kv(1, _tokens_of(1))
+    store.insert(1, v, kv_nbytes(v), 0.0, prefix_len=_tokens_of(1))
+    psi = store.consume(1).value
+    kv = psi.materialize_async()
+    assert isinstance(kv, tuple) and _same(kv, psi.materialize())
+    assert store.pool.h2d["pulls_deferred"] == 0
+
+
+def test_deferred_pull_reads_the_pages_before_a_landing_recycles_them():
+    """The window holds one entry: the next landing recycles the
+    consumed entry's pages at once, before its pull settles.  The pull
+    was dispatched first, so it still reads the consumed psi."""
+    tokens = _tokens_of(1)
+    store = PagedHBMStore(LAYOUT.entry_bytes(tokens), LAYOUT,
+                          device_pool=True)
+    dram = DRAMExpander(ExpanderConfig(dram_budget_bytes=1e9))
+    ref = _spilled(dram, store, 1)
+    pages = set(store.entries[1].page_table.reshape(-1).tolist())
+    other = tuple(a + 100.0 for a in _kv(4, tokens))
+    store.insert(4, other, kv_nbytes(other), 1.0, prefix_len=tokens)
+    assert 1 not in store.entries
+    assert set(store.entries[4].page_table.reshape(-1).tolist()) == pages
+    assert store.pool.pending is not None
+    assert _same(dram.lookup(1).value, ref)
+    assert _same(store.entries[4].value.materialize(),
+                 tuple(np.pad(a, ((0, 0), (0, 0), (0, 3), (0, 0), (0, 0)))
+                       for a in other))
+
+
+@pytest.mark.parametrize("read", ["reload", "take", "demote"])
+def test_reading_a_pending_copy_settles_it_first(read):
+    store = _store(True)
+    budget = LAYOUT.entry_bytes(_tokens_of(1))
+    dram = DRAMExpander(ExpanderConfig(dram_budget_bytes=budget))
+    demoted = []
+    dram.demote_sink = lambda e: demoted.append(e) or True
+    ref = _spilled(dram, store, 1)
+    pool = store.pool
+    assert isinstance(dram.entries[1].value, PendingPull)
+    if read == "reload":
+        store.pop(1)
+        action, d = dram.pseudo_pre_infer(1, store, 1.0)
+        assert action == "reload" and _same(d.value, ref)
+        dram.complete_reload(1, store, 1.0)
+        dram.finish(1)
+        served = store.resident(1).value.materialize()
+    elif read == "take":
+        served = dram.take(1).value
+    else:
+        from repro.core.cache import CacheEntry
+        host = _kv(4, _tokens_of(1))
+        assert dram.spill(CacheEntry(4, host, budget, 1.0,
+                                     prefix_len=_tokens_of(1)))
+        assert dram.stats["demotions"] == 1 and len(demoted) == 1
+        served = demoted[0].value
+    assert _same(served, ref)
+    assert pool.pending is None
+    assert pool.h2d["pulls_settled_forced"] == 1
+    assert _conserved(dram)
+
+
+def test_an_lru_drop_discards_a_pending_copy_unread():
+    store = _store(True)
+    budget = LAYOUT.entry_bytes(_tokens_of(1))
+    dram = DRAMExpander(ExpanderConfig(dram_budget_bytes=budget))
+    _spilled(dram, store, 1)
+    from repro.core.cache import CacheEntry
+    host = _kv(4, _tokens_of(1))
+    assert dram.spill(CacheEntry(4, host, budget, 1.0,
+                                 prefix_len=_tokens_of(1)))
+    pool = store.pool
+    assert 1 not in dram.entries and dram.stats["evictions"] == 1
+    assert pool.pending is None
+    assert pool.h2d["pulls_settled_forced"] == \
+        pool.h2d["pulls_settled_idle"] == 0
+    assert _conserved(dram)
+
+
+USERS = (0, 1, 3)
+
+
+def test_a_pool_keeps_at_most_one_pending_pull():
+    """Starting a pull, or a synchronous copy, settles the pool's
+    pending pull first: one dense copy at most waits on the device."""
+    store = _store(True)
+    pool = store.pool
+    assert sum(LAYOUT.entry_pages(_tokens_of(u)) for u in USERS) \
+        <= POOL_PAGES, "no landing may recycle the pages under a pull"
+    psis, refs = {}, {}
+    for uid in USERS:
+        v = _kv(uid, _tokens_of(uid))
+        store.insert(uid, v, kv_nbytes(v), float(uid),
+                     prefix_len=_tokens_of(uid))
+        psis[uid] = store.consume(uid).value
+        refs[uid] = psis[uid].materialize()
+    pulls = []
+    for uid in USERS:
+        pulls.append(psis[uid].materialize_async())
+        assert pool.pending is pulls[-1]
+        assert [p.settled for p in pulls] == [True] * (len(pulls) - 1) \
+            + [False]
+    assert pool.h2d["pulls_settled_forced"] == 2
+    psis[0].materialize()
+    assert pool.pending is None and pool.h2d["pulls_settled_forced"] == 3
+    for uid, pull in zip(USERS, pulls):
+        assert _same(pull.result(), refs[uid])
+    assert pool.h2d["pulls_deferred"] == 3
+    assert pool.h2d["pulls_settled_forced"] == 3
+
+
+def test_deferred_spills_keep_the_tier_ledger_and_count_each_pull_once():
+    store = _store(True)
+    dram = DRAMExpander(ExpanderConfig(
+        dram_budget_bytes=2 * LAYOUT.entry_bytes(_tokens_of(2))))
+    demoted = []
+    dram.demote_sink = lambda e: demoted.append(e) or len(demoted) % 2 == 0
+    pool = store.pool
+    copied = 0
+    for i, uid in enumerate([0, 1, 2, 3, 4, 5, 0, 2]):
+        if uid in store.entries:
+            store.pop(uid)
+        _spilled(dram, store, uid, float(i))
+        copied += 2 * LAYOUT.entry_bytes(_tokens_of(uid))  # ref + pull
+        assert _conserved(dram)
+    dram.take(2)
+    assert _conserved(dram)
+    assert pool.h2d["d2h_bytes"] == pool.h2d["materialized_bytes"] == copied
+    assert pool.h2d["pulls_deferred"] == 8
+    settled = pool.h2d["pulls_settled_forced"] + \
+        pool.h2d["pulls_settled_idle"]
+    assert settled <= pool.h2d["pulls_deferred"]
+    assert all(not isinstance(e.value, PendingPull) for e in demoted)

@@ -49,7 +49,7 @@ def _executor(live, max_batch=4):
         page_tokens=PT, device_pool=True)
 
 
-def _service(live, tracer=None):
+def _service(live, tracer=None, clock=None):
     cfg = live[0]
     layout = PageLayout.from_model_config(cfg, PT)
     budget = 64 * layout.entry_bytes(512)
@@ -64,7 +64,7 @@ def _service(live, tracer=None):
                               trigger_policy="admit-all",
                               long_seq_threshold=1))
     svc = RelayGRService(rcfg, GRCostModel(cfg), executor_factory=lambda n: ex,
-                         tracer=tracer)
+                         clock=clock, tracer=tracer)
     return svc, ex
 
 
@@ -139,7 +139,10 @@ def test_spans_nest_event_exec_prepare_put_wait(traced):
         elif s.name in ("exec.prepare", "exec.put", "exec.wait"):
             assert parent in ("exec.rank", "exec.prefill")
         elif s.name == "window.materialize":
-            assert parent == "dram.spill"
+            # a synchronous copy runs inside the spill; a deferred pull
+            # settles in drain between events, or inside the handler
+            # that needed it first
+            assert parent in ("dram.spill", "relay.event", None)
         else:
             assert parent == "relay.event", (s.name, parent)
         assert s.t0 <= s.t1
@@ -165,6 +168,67 @@ def test_spans_and_marks_of_a_request_share_its_ids(traced):
         if s.name == "exec.rank":
             assert s.args["rows"] == len(s.args["uids"]) \
                 == len(s.args["lens"])
+
+
+def _pools(svc):
+    return [i.hbm.pool for i in svc.instances.values()]
+
+
+def _h2d(svc, key):
+    return sum(p.h2d[key] for p in _pools(svc))
+
+
+def test_sink_runs_before_the_spill_pull_settles(traced):
+    """The consume-time spill only starts its pull: the scores reach
+    the sink first, and the pull settles (``window.materialize``)
+    after it, outside the spill."""
+    tracer, svc, _, results = traced
+    spans = tracer.spans
+    assert _h2d(svc, "pulls_deferred") == len(results)
+    spills = [i for i, s in enumerate(spans) if s.name == "dram.spill"]
+    assert len(spills) == len(results)
+    for i in spills:
+        uid = spans[i].args["uid"]
+        sink = next(s for s in spans[i:] if s.name == "relay.sink"
+                    and s.args["uid"] == uid)
+        settle = next(s for s in spans[i:]
+                      if s.name == "window.materialize")
+        assert sink.t1 <= settle.t0
+        assert not any(spans[c].name == "window.materialize"
+                       for c in tracer.children(i))
+
+
+def test_nothing_is_pending_after_drain(traced):
+    _, svc, _, _ = traced
+    assert not svc.runtime._pulls
+    assert all(p.pending is None for p in _pools(svc))
+    assert _h2d(svc, "pulls_deferred") == \
+        _h2d(svc, "pulls_settled_idle") + _h2d(svc, "pulls_settled_forced")
+    for inst in svc.instances.values():
+        for e in inst.expander.entries.values():
+            assert all(isinstance(a, np.ndarray) for a in e.value)
+
+
+def test_drain_settles_pulls_while_the_next_event_is_far_off(live):
+    """With the next arrival seconds away on the runtime's clock, the
+    loop settles each spill's pull before it waits, and the DRAM copy
+    it settles is the one a synchronous pull gives."""
+    from repro.core import VirtualClock
+    svc, _ = _service(live, clock=VirtualClock())
+    store = live[3]
+    metas = [UserMeta(user_id=u, prefix_len=int(store.long_term(u).shape[0]),
+                      incr_len=8, n_items=16) for u in (340, 341)]
+    svc.runtime.run([(0.0, metas[0]), (5.0, metas[1])])
+    assert _h2d(svc, "pulls_deferred") == 2
+    assert _h2d(svc, "pulls_settled_idle") == 1      # before t = 5
+    assert _h2d(svc, "pulls_settled_forced") == 1    # the drain's end
+    for inst in svc.instances.values():
+        for uid, d in inst.expander.entries.items():
+            e = inst.hbm.entries[uid]
+            psi = inst.hbm.acquire_value(e)
+            assert all(a.tobytes() == b.tobytes()
+                       for a, b in zip(d.value, psi.materialize()))
+            inst.hbm.release_value(psi)
 
 
 def test_self_time_is_duration_less_children(traced):
